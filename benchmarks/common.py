@@ -1,8 +1,10 @@
 """Shared benchmark helpers.
 
-All wall-clock numbers are CPU (this container has no TPU); each benchmark also
-derives modeled-TPU quantities (bytes moved, roofline throughput) so the table
-structure matches the paper's figures.  Output format: ``name,us_per_call,derived``.
+Wall-clock numbers are times on whatever platform JAX runs on: a CPU run says
+nothing about a TPU, and no figure here has been measured on a chip yet
+(``chip_smoke.py`` is the chip run).  Each benchmark also derives modeled-TPU
+quantities (bytes moved, roofline throughput) so the table structure matches the
+paper's figures.  Output format: ``name,us_per_call,derived``.
 """
 from __future__ import annotations
 

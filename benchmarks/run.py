@@ -9,6 +9,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from benchmarks import (ckpt_grad, fig12_bitpack, fig13_rle, fig14_ans,
                         fig15_ans_chunks, fig16_tpch_ratio,
                         fig17_tpch_throughput, fig18_fusion, fig19_e2e,
@@ -30,6 +32,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated module keys")
     args = ap.parse_args()
+    enable_compile_cache()
     keys = args.only.split(",") if args.only else list(MODULES)
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -4,10 +4,13 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import plan as P
 from repro.core.compiler import compile_decoder, device_buffers
 from repro.core.fusion import fuse
 from repro.core.plan import lower
+
+enable_compile_cache()
 
 # 1. some TPC-H-shaped data: dates with ~2.5k distinct values
 rng = np.random.default_rng(0)
@@ -33,7 +36,8 @@ print(f"graph: {graph.nesting}, {len(graph.buffers)} transfer buffers, "
       f"signature {graph.signature[:12]}")
 
 # 5. move the compressed buffers and decode on device (pure-jnp backend here;
-#    backend='pallas' runs the TPU kernels, interpret=True off-TPU)
+#    backend='pallas' runs the Pallas kernels and needs interpret= passed
+#    explicitly -- the TPU compiler refuses them today, so interpret=True)
 decoder = compile_decoder(enc, backend="jnp", fuse=True)
 out = decoder(device_buffers(enc))
 assert np.array_equal(np.asarray(out), column)

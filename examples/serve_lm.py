@@ -6,11 +6,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SMOKES
 from repro.models import get_model
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.kvcache import page_in, page_out
 
+enable_compile_cache()
 cfg = SMOKES["qwen1.5-0.5b"]
 model = get_model(cfg)
 params, _ = model.init(jax.random.PRNGKey(0))

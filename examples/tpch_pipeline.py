@@ -2,7 +2,8 @@
 
 Compresses the columns of TPC-H Q1/Q6 with the paper's Table-2 plans, moves them
 host->device with Johnson-ordered pipelining, decompresses, and runs the queries in
-the JAX mini-engine.  Compares noCOMP / cascaded-baseline / ZipFlow movement costs.
+the JAX mini-engine.  Both the materialized and the decode-fused answers are
+checked against a float64 NumPy reference at rtol=1e-4.
 
 Run:  PYTHONPATH=src python examples/tpch_pipeline.py [--scale 0.01]
 """
@@ -11,14 +12,13 @@ import os
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.data.queries import QUERY_PLANS, q1_engine, q6_engine
-from repro.core import plan as P
+from repro.compile_cache import enable_compile_cache
 from repro.core.costmodel import CostModel
 from repro.data.columns import TABLE2_PLANS
 from repro.data.loader import ColumnPipeline
+from repro.data.queries import ANSWERS, ENGINES, NUMPY_REFERENCES, QUERY_PLANS
 from repro.data.tpch import QUERY_COLUMNS, generate
 
 ap = argparse.ArgumentParser()
@@ -57,6 +57,7 @@ ap.add_argument("--placement", default=None, choices=["sharded"],
                      "rebalance over the D2D fabric when that is modeled "
                      "faster (decode-where-landed)")
 args = ap.parse_args()
+enable_compile_cache()
 chunk_bytes = "auto" if args.auto_chunks else (args.chunk_kib * 1024 or None)
 
 cost_model = None
@@ -74,10 +75,12 @@ cols = generate(scale=args.scale, seed=0)
 print(f"generated TPC-H-like tables at scale {args.scale} "
       f"({cols['L_ORDERKEY'].size:,} lineitems)")
 
-for q, engine in ((1, q1_engine), (6, q6_engine)):
+for q in (1, 6):
     names = QUERY_COLUMNS[q]
     qcols = {n: cols[n] for n in names}
     raw_bytes = sum(a.nbytes for a in qcols.values())
+    # float64 NumPy answer both engines are held to
+    ref = NUMPY_REFERENCES[q](qcols)
 
     pipe = ColumnPipeline({n: TABLE2_PLANS[n] for n in names},
                           chunk_bytes=chunk_bytes,
@@ -103,8 +106,9 @@ for q, engine in ((1, q1_engine), (6, q6_engine)):
         device_cols = {n: jax.device_put(a, jax.devices()[0])
                        for n, a in device_cols.items()}
     t0 = time.perf_counter()
-    out = jax.block_until_ready(jax.jit(engine)(device_cols))
+    out = jax.block_until_ready(jax.jit(ENGINES[q])(device_cols))
     t_query = time.perf_counter() - t0
+    np.testing.assert_allclose(ANSWERS[q](out), ref, rtol=1e-4)
     print(f"\nTPC-H Q{q}: {raw_bytes / 1e6:.1f} MB raw -> "
           f"{comp_bytes / 1e6:.2f} MB compressed "
           f"({raw_bytes / comp_bytes:.1f}x)")
@@ -159,8 +163,7 @@ for q, engine in ((1, q1_engine), (6, q6_engine)):
     t0 = time.perf_counter()
     qe = pipe.run_query(qp)
     t_fused = time.perf_counter() - t0
-    np.testing.assert_allclose(np.asarray(qe.result), np.asarray(out),
-                               rtol=1e-4)
+    np.testing.assert_allclose(ANSWERS[q](qe.result), ref, rtol=1e-4)
     ep_q = pipe.query_plan(qp)
     n_fused = sum(d.fused for d in ep_q.decisions.values())
     print(f"   decode-fused Q{q}: {t_fused * 1e3:.1f} ms warm (cold "

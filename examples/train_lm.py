@@ -15,6 +15,7 @@ import tempfile
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SMOKES
 from repro.data.loader import CompressedTokenLoader
 from repro.models import get_model
@@ -31,6 +32,7 @@ ap.add_argument("--seq", type=int, default=128)
 ap.add_argument("--d-model", type=int, default=128)
 ap.add_argument("--layers", type=int, default=4)
 args = ap.parse_args()
+enable_compile_cache()
 
 cfg = dataclasses.replace(
     SMOKES["qwen1.5-0.5b"], d_model=args.d_model, n_layers=args.layers,

@@ -11,9 +11,13 @@ over that pipeline; ``get_chunk``/``compile_chunk_graph`` build the per-chunk de
 programs the streaming executor launches chunk-by-chunk.
 
 Backends:
-  * "jnp"      -- pure jax.numpy stages (reference semantics; fast on CPU; also what a
-                  TPU falls back to when a shape is hostile to the Pallas kernels).
-  * "pallas"   -- the Pallas TPU kernels of ``repro.kernels`` (interpret=True off-TPU).
+  * "jnp"      -- pure jax.numpy stages: the default, and the backend the streaming
+                  executor runs on every platform.
+  * "pallas"   -- the Pallas TPU kernels of ``repro.kernels``.  The caller passes
+                  ``interpret`` (True off a TPU); nothing picks it from the backend.
+                  The TPU compiler refuses these kernels today (1-D dynamic
+                  indexing of VMEM-resident buffers), so they run in interpret mode
+                  only.
   * "baseline" -- the nvCOMP role: fixed geometry, **no fusion**, every stage
                   materializes its output (paper §5.2/§5.3 baseline behaviour).
 """
@@ -29,7 +33,7 @@ import jax.numpy as jnp
 
 from repro.core import fusion as fusion_mod
 from repro.core import plan as plan_mod
-from repro.core.geometry import DEFAULT_CHIP, Geometry, chip as chip_spec, native_config
+from repro.core.geometry import Geometry, chip as chip_spec, native_config, resolve_chip
 from repro.core.ir import (DecodeGraph, element_chunk_layout, group_chunk_layout,
                            query_chunk_layout)
 from repro.core.patterns import Aux, Ctx, GroupParallel, Stage
@@ -94,7 +98,7 @@ class Program:
 
 
 def compile_graph(graph: DecodeGraph, backend: str = "jnp",
-                  chip: str = DEFAULT_CHIP,
+                  chip: str | None = None,
                   geometry: dict[str, Geometry] | None = None,
                   interpret: bool | None = None,
                   jit: bool = True) -> Program:
@@ -104,8 +108,9 @@ def compile_graph(graph: DecodeGraph, backend: str = "jnp",
     if backend == "baseline":
         # fixed library geometry, deliberately not adapted to the chip (paper §5.2)
         geoms = dict(BASELINE_GEOMS)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    if backend == "pallas" and interpret is None:
+        raise ValueError("backend='pallas' needs interpret= passed explicitly "
+                         "(True off a TPU)")
     stages = graph.stages
 
     def decode(bufs: dict[str, jnp.ndarray]) -> jnp.ndarray:
@@ -508,9 +513,10 @@ class ProgramCache:
         return prog
 
     def get(self, graph: DecodeGraph, backend: str = "jnp",
-            chip: str = DEFAULT_CHIP,
+            chip: str | None = None,
             geometry: dict[str, Geometry] | None = None,
             interpret: bool | None = None, jit: bool = True) -> Program:
+        chip = resolve_chip(chip)
         key = (graph.signature, backend, chip, _geometry_key(geometry),
                interpret, jit)
         return self._get(key, lambda: compile_graph(
@@ -571,7 +577,7 @@ def build_graph(enc: plan_mod.Encoded, fuse: bool = True) -> DecodeGraph:
 
 
 def compile_blob(enc: plan_mod.Encoded, backend: str = "jnp", fuse: bool = True,
-                 chip: str = DEFAULT_CHIP,
+                 chip: str | None = None,
                  geometry: dict[str, Geometry] | None = None,
                  interpret: bool | None = None, jit: bool = True,
                  cache: ProgramCache | None = None) -> Program:
@@ -603,7 +609,7 @@ class CompiledDecoder:
 
 
 def compile_decoder(enc: plan_mod.Encoded, backend: str = "jnp", fuse: bool = True,
-                    chip: str = DEFAULT_CHIP,
+                    chip: str | None = None,
                     geometry: dict[str, Geometry] | None = None,
                     interpret: bool | None = None,
                     jit: bool = True) -> CompiledDecoder:

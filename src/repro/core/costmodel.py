@@ -35,7 +35,7 @@ from typing import Mapping, Sequence
 import numpy as np
 
 from repro.core import scheduler
-from repro.core.geometry import DEFAULT_CHIP, chip as chip_spec, native_subtile
+from repro.core.geometry import chip as chip_spec, native_subtile
 
 
 def rows_per_chunk(shape0: int, nbytes: int, chunk_bytes: int) -> int:
@@ -355,10 +355,12 @@ class CostModel:
     ``measured`` is the authoritative wall-clock store (the executor's
     ``timings`` dict aliases it); ``observe`` additionally folds each
     measurement into the transfer/decode calibration scales so chip-model
-    estimates for unmeasured columns land in wall-clock units.
+    estimates for unmeasured columns land in wall-clock units.  ``chip``
+    names the ``geometry.CHIPS`` entry; None reads it from the attached TPU
+    (``geometry.resolve_chip``).
     """
 
-    def __init__(self, chip: str = DEFAULT_CHIP, alpha: float = 0.4):
+    def __init__(self, chip: str | None = None, alpha: float = 0.4):
         self.spec = chip_spec(chip)
         self.alpha = float(alpha)
         self.transfer_scale = 1.0
@@ -611,7 +613,7 @@ class CostModel:
         process is already in wall-clock units."""
         with open(path) as f:
             data = json.load(f)
-        cm = cls(chip=data.get("chip", DEFAULT_CHIP),
+        cm = cls(chip=data.get("chip"),
                  alpha=float(data.get("alpha", 0.4)))
         cm.transfer_scale = float(data.get("transfer_scale", 1.0))
         cm.decode_scale = float(data.get("decode_scale", 1.0))

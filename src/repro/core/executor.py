@@ -56,7 +56,7 @@ from repro.core import costmodel, planner as planner_mod
 from repro.core import plan as plan_mod, scheduler
 from repro.core.compiler import DEFAULT_CACHE, Program, ProgramCache
 from repro.core.costmodel import CostModel, profile_from
-from repro.core.geometry import DEFAULT_CHIP
+from repro.core.geometry import resolve_chip
 from repro.core.ir import DecodeGraph, element_chunk_layout, group_chunk_layout
 from repro.core.planner import ExecutionPlan
 
@@ -478,7 +478,7 @@ class StreamingExecutor:
                  chunk_bytes: int | None | str = 1 << 20, pipeline: bool = True,
                  batch_columns: bool = True, prefetch_chunks: int | None = None,
                  chunk_decode: bool = False,
-                 chip: str = DEFAULT_CHIP, cache: ProgramCache | None = None,
+                 chip: str | None = None, cache: ProgramCache | None = None,
                  policy: str = "chunk-johnson",
                  cost_model: CostModel | None = None,
                  async_dispatch: bool = False):
@@ -495,10 +495,10 @@ class StreamingExecutor:
         self.prefetch_chunks = (None if prefetch_chunks is None
                                 else max(1, prefetch_chunks))
         self.chunk_decode = chunk_decode
-        self.chip = chip
+        self.chip = resolve_chip(chip)
         self.cache = cache if cache is not None else DEFAULT_CACHE
         self.policy = policy
-        self.cost_model = cost_model or CostModel(chip=chip)
+        self.cost_model = cost_model or CostModel(chip=self.chip)
         self._encoded: dict[str, plan_mod.Encoded] = {}
         self._graphs: dict[str, DecodeGraph] = {}
         self._programs: dict[str, Program] = {}
@@ -1285,9 +1285,8 @@ class StreamingExecutor:
     def _d2d_target(self, mesh_plan, devices, dst_logical: int):
         """(physical device id, jax device) for a redistribution leg's
         destination logical device."""
-        ids = mesh_plan.device_ids
-        dst_id = int(ids[dst_logical % len(ids)]) if ids else int(dst_logical)
-        return dst_id, devices[dst_id % len(devices)]
+        dst_id = int(mesh_plan.device_ids[dst_logical])
+        return dst_id, devices[dst_id]
 
     def _copy_shard_d2d(self, rec: ColumnExec, dst_logical: int, mesh_plan,
                         devices) -> tuple[ColumnExec, int, object, float]:
@@ -1319,13 +1318,20 @@ class StreamingExecutor:
         devices one at a time.  Results are bitwise identical either way
         (per-column sequence numbers fix chunk order; assembly is unchanged);
         measured per-link actuals feed ``CostModel.observe_link`` in both
-        modes."""
+        modes.  A plan that names a device JAX does not have raises
+        ``ValueError``; it is never folded onto fewer devices."""
         if encs is not None:
             for name, enc in encs.items():
                 if (self._programs.get(name) is None
                         or self._encoded.get(name) is not enc):
                     self.compile(name, enc)
         devices = jax.devices()
+        missing = sorted({int(d) for d in mesh_plan.device_ids}
+                         - set(range(len(devices))))
+        if missing:
+            raise ValueError(
+                f"mesh plan names devices {missing}, but JAX has only "
+                f"{len(devices)} device(s)")
         active = sum(1 for p in mesh_plan.plans if p.order)
         if concurrent is None:
             concurrent = active > 1
@@ -1340,7 +1346,7 @@ class StreamingExecutor:
         d2d_done: dict[str, tuple[int, int, float]] = {}
         for li, dplan in enumerate(mesh_plan.plans):
             dev_id = int(mesh_plan.device_ids[li])
-            dev = devices[dev_id % len(devices)]
+            dev = devices[dev_id]
             d_items = list(dplan.order)
             per_device[dev_id] = tuple(d_items)
             launches = 0
@@ -1418,7 +1424,7 @@ class StreamingExecutor:
                 device_launches[dev_id] = 0
                 if not d_items:
                     continue
-                dev = devices[dev_id % len(devices)]
+                dev = devices[dev_id]
                 items: list[_TransferItem] = []
                 acq: list[bool] = []
                 rel: list[bool] = []

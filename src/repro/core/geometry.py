@@ -96,11 +96,44 @@ CHIPS: dict[str, ChipSpec] = {
                     host_link_gbps=64.0),
 }
 
-DEFAULT_CHIP = "v5e"
+# ``jax.Device.device_kind`` of each TPU generation in the table, as JAX reports
+# it (checked against described ``v4:2x2x1``/``v5p:2x2x1``/``v5e:2x2``/``v6e:2x2``
+# topologies).  A TPU whose kind is missing here is an error, never planned
+# with another chip's numbers.
+DEVICE_KINDS: dict[str, str] = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v6 lite": "v6e",
+}
+
+# Off a TPU (CPU tests and rehearsals) there is no chip to read, so plans model
+# the chip this repository targets.
+MODEL_CHIP = "v5e"
 
 
-def chip(name: str = DEFAULT_CHIP) -> ChipSpec:
-    return CHIPS[name]
+def resolve_chip(name: str | None = None) -> str:
+    """CHIPS key to plan for: ``name`` when given, else the attached TPU's
+    ``device_kind``, else ``MODEL_CHIP`` when JAX's devices are not TPUs."""
+    if name is not None:
+        if name not in CHIPS:
+            raise ValueError(f"unknown chip {name!r} (known: {sorted(CHIPS)})")
+        return name
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return MODEL_CHIP
+    try:
+        return DEVICE_KINDS[dev.device_kind]
+    except KeyError:
+        raise ValueError(f"no chip table entry for device_kind "
+                         f"{dev.device_kind!r} (known: "
+                         f"{sorted(DEVICE_KINDS)})") from None
+
+
+def chip(name: str | None = None) -> ChipSpec:
+    return CHIPS[resolve_chip(name)]
 
 
 # ----------------------------------------------------------------------------- spaces
@@ -193,8 +226,7 @@ def native_config(pattern: str, spec: ChipSpec, n_elems: int = 1 << 24,
 
 
 @functools.lru_cache(maxsize=None)
-def native_subtile(pattern: str, chip_name: str = DEFAULT_CHIP,
-                   itemsize: int = 4) -> int:
+def native_subtile(pattern: str, chip_name: str, itemsize: int = 4) -> int:
     """S*C of the chip's native config: the elements one grid-step sub-tile
     covers (one L-loop iteration).  The planner's chunk-size ladder snaps
     element-chunk boundaries to multiples of this, so every streamed decode

@@ -10,7 +10,7 @@ Data-dependent blocking: a tile starting at output offset o touches groups start
 ``fg = searchsorted(presum, o, 'right') - 1``.  ``fg`` per tile is precomputed with one
 cheap scan (the paper's one-time data scan) and fed through *scalar prefetch*, so the
 BlockSpec index maps DMA exactly the presum/value window each tile needs
-(element-indexed windows via ``repro.kernels.compat``).  A tile of T outputs
+(element-indexed ``pl.Element`` windows).  A tile of T outputs
 intersects at most T+1 groups (counts are >= 1), bounding the window statically.
 
 Value inputs whose tile ratio is a runtime meta operand (bitpack's ``bit_width``
@@ -32,7 +32,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.geometry import Geometry
 from repro.core.patterns import Ctx, GroupParallel
-from repro.kernels.compat import element_block_spec
 from repro.kernels.fully_parallel import _out_index_grid
 
 
@@ -86,8 +85,9 @@ def group_parallel_call(stage: GroupParallel, bufs: dict[str, jnp.ndarray],
         blen = (gcap * num) // den + (2 if den > 1 else 1)
         pad = jnp.zeros((blen + 2,), arr.dtype)
         value_arrays.append(jnp.concatenate([arr.reshape(-1), pad]))
-        value_specs.append(element_block_spec(
-            blen, lambda i, s, _n=num, _d=den: ((s[i] * _n) // _d,)))
+        value_specs.append(pl.BlockSpec(
+            (pl.Element(blen),),
+            lambda i, s, _n=num, _d=den: ((s[i] * _n) // _d,)))
         value_units.append((num, den))
     extra_arrays = [bufs[k] for k in stage.extra_inputs]
     extra_specs = [pl.BlockSpec(a.shape, lambda i, s, _nd=a.ndim: (0,) * _nd)
@@ -116,7 +116,7 @@ def group_parallel_call(stage: GroupParallel, bufs: dict[str, jnp.ndarray],
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
-        in_specs=[element_block_spec(gcap + 2, lambda i, s: (s[i],))]
+        in_specs=[pl.BlockSpec((pl.Element(gcap + 2),), lambda i, s: (s[i],))]
         + value_specs + extra_specs,
         out_specs=pl.BlockSpec((rows, cols), lambda i, s: (i, 0)),
     )

@@ -1,7 +1,10 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST precede every other import: jax locks the device count at
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
+# The lines above MUST precede every other import: jax locks the device count at
 # first initialization, and the production meshes below need 512 host placeholders.
+# Only a run as a script sets the flag; importing this module changes nothing.
 
 """Multi-pod dry-run: prove the distribution config is coherent without hardware.
 

@@ -46,7 +46,9 @@ def main() -> None:
     cfg = (SMOKES if args.smoke else ARCHS)[args.arch]
     model = get_model(cfg)
     if args.production_mesh:
-        os.environ.setdefault("LIBTPU_INIT_ARGS", TPU_PERF_FLAGS)
+        # append: flags already in the variable stay, and so do ours
+        os.environ["LIBTPU_INIT_ARGS"] = " ".join(
+            filter(None, (os.environ.get("LIBTPU_INIT_ARGS"), TPU_PERF_FLAGS)))
         mesh = make_production_mesh()
         set_mesh_context(mesh)
     params, specs = model.init(jax.random.PRNGKey(0))

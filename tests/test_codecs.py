@@ -69,6 +69,41 @@ def test_float2int_roundtrip(ks, d):
     roundtrip(P.Plan("float2int", children={"ints": mp("bitpack")}), arr)
 
 
+def test_float2int_decode_is_exact_on_any_backend():
+    """2-decimal prices decode with no exceptions and no float divide: a TPU
+    divide is not correctly rounded, so a divide in the decode would make
+    device output differ from the source there."""
+    import jax
+
+    k = np.arange(90_000, 10_500_000, 997, dtype=np.int64)
+    arr = (k / 100).astype(np.float32)
+    enc = roundtrip(P.Plan("float2int", children={"ints": mp("bitpack")}),
+                    arr)
+    assert enc.meta["decimals"] == 2 and enc.meta["n_exc"] == 0
+    prog = compile_decoder(enc).program
+    jaxpr = jax.make_jaxpr(prog.raw_fn)(device_buffers(enc))
+    float_divs = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "div"
+                  and np.issubdtype(e.outvars[0].aval.dtype, np.floating)]
+    assert not float_divs
+
+
+@pytest.mark.parametrize("lo, hi", [(-10_500_000, -90_000), (-100, 0)])
+def test_float2int_negative_decimals_have_no_exceptions(lo, hi):
+    """Negative 2-decimal values, large and below one in magnitude, decode
+    through the sign-magnitude split with no exceptions; -0.0 keeps its sign
+    bit as an exception."""
+    k = np.arange(lo, hi, max(1, (hi - lo) // 10_000), dtype=np.int64)
+    arr = (k / 100).astype(np.float32)
+    enc = roundtrip(P.Plan("float2int", children={"ints": mp("bitpack")}),
+                    arr)
+    assert enc.meta["decimals"] == 2 and enc.meta["n_exc"] == 0
+    neg_zero = np.array([-0.0, 1.5], np.float32)
+    enc = roundtrip(P.Plan("float2int", children={"ints": mp("bitpack")}),
+                    neg_zero)
+    assert enc.meta["n_exc"] == 1
+    assert (P.decode_np(enc).view(np.uint32) == neg_zero.view(np.uint32)).all()
+
+
 @settings(max_examples=20, deadline=None)
 @given(st.lists(st.tuples(st.integers(0, 10**6), st.integers(-5, 5),
                           st.integers(1, 50)), min_size=1, max_size=50))

@@ -9,10 +9,12 @@ from repro.core import plan as P, scheduler
 from repro.core.compiler import ProgramCache
 from repro.core.ir import query_chunk_layout
 from repro.core.fusion import hbm_traffic_bytes
-from repro.core.query import Bin, Col, Const, Pred, QueryPlan, lower_query
+from repro.core.query import (Bin, Col, Const, Pred, QueryPlan,
+                              blocked_segment_sum, lower_query)
 from repro.data.columns import TABLE2_PLANS
 from repro.data.loader import ColumnPipeline
-from repro.data.queries import Q1_PLAN, Q6_PLAN, q1_engine, q6_engine
+from repro.data.queries import (ANSWERS, ENGINES, NUMPY_REFERENCES, Q1_PLAN,
+                                Q6_PLAN, q1_engine, q6_engine)
 from repro.data.tpch import QUERY_COLUMNS, generate, scale_columns
 
 mp = P.make_plan
@@ -42,6 +44,68 @@ def _run_whole(fq, res=None):
     if res:
         bufs.update({k: jnp.asarray(v) for k, v in res.items()})
     return prog(bufs, 0)
+
+
+# ------------------------------------------------ accumulation / references
+
+@pytest.mark.parametrize("n", [1, 1000, 4096, 10_001])
+def test_blocked_segment_sum_matches_float64(n, rng):
+    import jax.numpy as jnp
+
+    lanes = [(rng.random(n) * 1e5).astype(np.float32) for _ in range(3)]
+    key = rng.integers(-1, 9, n).astype(np.int32)  # -1 and 8 drop, as in
+    ok = (key >= 0) & (key < 8)                    # jax.ops.segment_sum
+    got = np.asarray(blocked_segment_sum([jnp.asarray(v) for v in lanes],
+                                         jnp.asarray(key), 8))
+    want = np.stack([np.bincount(key[ok], weights=v[ok].astype(np.float64),
+                                 minlength=8) for v in lanes], axis=1)
+    assert got.shape == (8, 3)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    total = np.asarray(blocked_segment_sum([jnp.asarray(v) for v in lanes]))
+    np.testing.assert_allclose(total, [v.astype(np.float64).sum()
+                                       for v in lanes], rtol=1e-5)
+
+
+@pytest.mark.parametrize("q", [1, 6])
+def test_engines_match_float64_reference(q):
+    cols = _tpch(scale=0.01)
+    c = {n: cols[n] for n in QUERY_COLUMNS[q]}
+    ref = NUMPY_REFERENCES[q](c)
+    # the generator draws every (flag, status) pair: six Q1 groups
+    assert np.shape(ref) == ((6, 7) if q == 1 else ())
+    np.testing.assert_allclose(ANSWERS[q](ENGINES[q](c)), ref, rtol=1e-5)
+
+
+@pytest.mark.parametrize("path", ["engine", "fused"])
+def test_q1_real_groups_stay_distinct(path):
+    """TPC-H Q1's four groups (A,F) (N,F) (N,O) (R,F) come out as four rows,
+    each with its own rows' sums: no two flags share a segment."""
+    groups = [(b"A", 0), (b"N", 0), (b"N", 1), (b"R", 0)]   # F = 0, O = 1
+    per = [100, 200, 300, 400]                               # rows per group
+    n = sum(per)
+    cols = {
+        "L_RETURNFLAG": np.concatenate([np.full(k, g[0][0], np.uint8)
+                                        for g, k in zip(groups, per)]),
+        "L_LINESTATUS": np.concatenate([np.full(k, g[1], np.int32)
+                                        for g, k in zip(groups, per)]),
+        "L_QUANTITY": np.arange(n, dtype=np.int32) % 50 + 1,
+        "L_EXTENDEDPRICE": (np.arange(n) % 977 + 900).astype(np.float32),
+        "L_DISCOUNT": (np.arange(n) % 11 / 100).astype(np.float32),
+        "L_TAX": (np.arange(n) % 9 / 100).astype(np.float32),
+        "L_SHIPDATE": np.full(n, 9000, np.int32),
+    }
+    if path == "engine":
+        out = q1_engine(cols)
+    else:
+        pipe = ColumnPipeline({c: TABLE2_PLANS[c] for c in cols},
+                              chunk_bytes=1 << 12, chunk_decode=True)
+        pipe.compress(cols)
+        out = pipe.run_query(Q1_PLAN).result
+    got = ANSWERS[1](out)
+    ref = NUMPY_REFERENCES[1](cols)
+    assert [tuple(r) for r in got[:, :2]] == [(g[0][0], g[1]) for g in groups]
+    assert got[:, -1].tolist() == per
+    np.testing.assert_allclose(got, ref, rtol=1e-5)
 
 
 # ------------------------------------------------------ fused == engine

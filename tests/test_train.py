@@ -102,17 +102,8 @@ def test_grad_compression_unbiased_convergence():
     wire format is 4x smaller."""
     from repro.train import grad_compress as gc
 
-    # jax < 0.5 has neither jax.sharding.AxisType nor jax.shard_map (and its
-    # shard_map spells check_vma as check_rep) -- probe instead of pinning
-    mesh_kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        mesh_kwargs["axis_types"] = (jax.sharding.AxisType.Auto,)
-    mesh = jax.make_mesh((1,), ("pod",), **mesh_kwargs)
-    if hasattr(jax, "shard_map"):
-        shard_map, check_kwargs = jax.shard_map, {"check_vma": False}
-    else:
-        from jax.experimental.shard_map import shard_map
-        check_kwargs = {"check_rep": False}
+    mesh = jax.make_mesh((1,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     target = jnp.asarray(np.random.default_rng(0).normal(size=(64,)),
                          jnp.float32)
 
@@ -121,10 +112,10 @@ def test_grad_compression_unbiased_convergence():
         gsum, err = gc.compressed_psum(g, err, "pod")
         return w - 0.05 * gsum, err
 
-    stepped = jax.jit(shard_map(
+    stepped = jax.jit(jax.shard_map(
         one_step, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(),) * 2,
-        out_specs=(jax.sharding.PartitionSpec(),) * 2, **check_kwargs))
+        out_specs=(jax.sharding.PartitionSpec(),) * 2, check_vma=False))
     w = jnp.zeros((64,))
     err = jnp.zeros((64,))
     for _ in range(200):
