@@ -36,7 +36,7 @@ from repro.core import plan as plan_mod
 from repro.core.geometry import Geometry, chip as chip_spec, native_config, resolve_chip
 from repro.core.ir import (DecodeGraph, element_chunk_layout, group_chunk_layout,
                            query_chunk_layout)
-from repro.core.patterns import Aux, Ctx, GroupParallel, Stage
+from repro.core.patterns import Aux, Ctx, GroupParallel, NonParallel, Stage
 
 
 def _run_stage(st: Stage, bufs: dict[str, jnp.ndarray], backend: str,
@@ -50,6 +50,25 @@ def _run_stage(st: Stage, bufs: dict[str, jnp.ndarray], backend: str,
 
 BASELINE_GEOMS = {"fp": Geometry(1, 8, 128), "gp": Geometry(1, 8, 128),
                   "np": Geometry(1, 8, 128)}
+
+
+def pattern_of(graph: DecodeGraph) -> str:
+    """The graph's parallel pattern: ``np`` if any stage is Non-Parallel,
+    else ``gp`` if any is Group-Parallel, else ``fp`` (``Aux`` and ``Reduce``
+    stages do not count)."""
+    if any(isinstance(st, NonParallel) for st in graph.stages):
+        return "np"
+    if any(isinstance(st, GroupParallel) for st in graph.stages):
+        return "gp"
+    return "fp"
+
+
+def _named(fn: Callable, kind: str, pattern: str) -> Callable:
+    """Name a program body ``<kind>_<pattern>`` before ``jax.jit``, so its
+    XLA module reads ``jit_<kind>_<pattern>`` in a device trace.  The name
+    depends on nothing else, so the persistent compile cache still hits."""
+    fn.__name__ = fn.__qualname__ = f"{kind}_{pattern}"
+    return fn
 
 
 @dataclasses.dataclass
@@ -91,7 +110,8 @@ class Program:
         """Decode K same-signature columns stacked on a new leading axis: one
         launch instead of K (multi-column batched decode)."""
         if self._batched is None:
-            vfn = jax.vmap(self.raw_fn)
+            vfn = _named(jax.vmap(self.raw_fn), "decode_batched",
+                         pattern_of(self.graph))
             self._batched = jax.jit(vfn) if self.jit else vfn
         self.batched_calls += 1
         return self._batched(stacked)
@@ -121,6 +141,7 @@ def compile_graph(graph: DecodeGraph, backend: str = "jnp",
             env[st.out] = out
         return out
 
+    _named(decode, "decode", pattern_of(graph))
     fn = jax.jit(decode) if jit else decode
     return Program(fn=fn, raw_fn=decode, graph=graph, backend=backend, jit=jit)
 
@@ -182,7 +203,8 @@ def compile_chunk_graph(graph: DecodeGraph, chunk_elems: int,
             produced.add(st.out)
         return out
 
-    fn = jax.jit(decode_chunk) if jit else decode_chunk
+    fn = _named(decode_chunk, "decode_chunk", pattern_of(graph))
+    fn = jax.jit(fn) if jit else fn
     return ChunkProgram(fn=fn, graph=graph, chunk_elems=int(chunk_elems), jit=jit)
 
 
@@ -251,7 +273,8 @@ def compile_query_chunk_graph(graph: DecodeGraph, chunk_elems: int,
             produced.add(st.out)
         return out
 
-    fn = jax.jit(partial_chunk) if jit else partial_chunk
+    fn = _named(partial_chunk, "query_chunk", pattern_of(graph))
+    fn = jax.jit(fn) if jit else fn
     return QueryChunkProgram(fn=fn, graph=graph, chunk_elems=int(chunk_elems),
                              jit=jit)
 
@@ -292,7 +315,8 @@ def compile_group_prologue(graph: DecodeGraph, jit: bool = True
             env[st.out] = st.run_jnp(env)
         return {nm: env[nm] for nm in needed}
 
-    fn = jax.jit(run_prologue) if jit else run_prologue
+    fn = _named(run_prologue, "decode_prologue", pattern_of(graph))
+    fn = jax.jit(fn) if jit else fn
     return PrologueProgram(fn=fn, graph=graph, jit=jit)
 
 
@@ -420,7 +444,8 @@ def compile_group_chunk_graph(graph: DecodeGraph, g_size: int, pad_elems: int,
             produced.add(st.out)
         return out
 
-    fn = jax.jit(decode_span) if jit else decode_span
+    fn = _named(decode_span, "decode_span", pattern_of(graph))
+    fn = jax.jit(fn) if jit else fn
     return GroupChunkProgram(fn=fn, graph=graph, g_size=g_size,
                              pad_elems=pad_elems, jit=jit)
 

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import costmodel, planner as planner_mod
 from repro.core import plan as plan_mod, scheduler
 from repro.core.compiler import DEFAULT_CACHE, Program, ProgramCache
@@ -158,6 +159,7 @@ class _InlineIssuer:
     def __init__(self, items: list, device, issue_s: dict[str, float]):
         self._items = items
         self._device = device
+        self._scan = obs.current_scan()
         self.issue_s = issue_s
         self.total = len(items)
         self.committed = 0
@@ -165,10 +167,10 @@ class _InlineIssuer:
     def advance(self, target: int) -> None:
         while self.committed < min(target, self.total):
             name, dest, i, piece = self._items[self.committed]
-            t = time.perf_counter()
-            dest[i] = jax.device_put(piece, self._device)   # async H2D
-            self.issue_s[name] = (self.issue_s.get(name, 0.0)
-                                  + time.perf_counter() - t)
+            with obs.span("zipflow.put", column=name, bytes=piece.nbytes,
+                          scan=self._scan) as put:
+                dest[i] = jax.device_put(piece, self._device)   # async H2D
+            self.issue_s[name] = self.issue_s.get(name, 0.0) + put.s
             self.committed += 1
 
     def wait(self, target: int) -> None:      # advance already committed them
@@ -203,6 +205,8 @@ class _WorkerIssuer:
         self._items = items
         self._device = device
         self._sync = sync
+        # the dispatcher's scan: puts on the worker thread carry its id
+        self._scan = obs.current_scan()
         self.issue_s = issue_s
         self.total = len(items)
         self.committed = 0
@@ -238,15 +242,16 @@ class _WorkerIssuer:
                         while not self._budget.acquire(timeout=0.1):
                             if self._stop:
                                 return
-                    t = time.perf_counter()
-                    buf = jax.device_put(piece, self._device)  # async H2D
-                    if self._sync:
-                        # D2D copy legs block here so issue_s records the
-                        # true copy duration (this worker has nothing else
-                        # to do; the dispatcher keeps launching decodes)
-                        jax.block_until_ready(buf)
-                    self.issue_s[name] = (self.issue_s.get(name, 0.0)
-                                          + time.perf_counter() - t)
+                    with obs.span("zipflow.put", column=name,
+                                  bytes=piece.nbytes, scan=self._scan) as put:
+                        buf = jax.device_put(piece, self._device)  # async H2D
+                        if self._sync:
+                            # D2D copy legs block here so issue_s records
+                            # the true copy duration (this worker has nothing
+                            # else to do; the dispatcher keeps launching
+                            # decodes)
+                            jax.block_until_ready(buf)
+                    self.issue_s[name] = self.issue_s.get(name, 0.0) + put.s
                     dest[slot] = buf
                     with self._cv:
                         self.committed = i + 1
@@ -361,7 +366,10 @@ class DispatchEngine:
                     if not any_err and all(
                             i.committed < min(need[k], i.total)
                             for k, (_, i) in live.items()):
-                        self._cv.wait(timeout=0.05)
+                        # every leg waits on a worker's device_put: the
+                        # issue step, not a transfer's completion
+                        with obs.span("zipflow.wait_issue"):
+                            self._cv.wait(timeout=0.05)
         return results
 
     def close(self) -> None:
@@ -381,9 +389,9 @@ def _drive_seq(gen, issuer):
             _, n = gen.send(wait_s)
         except StopIteration as stop:
             return stop.value
-        t0 = time.perf_counter()
-        issuer.wait(n)
-        wait_s = time.perf_counter() - t0
+        with obs.span("zipflow.wait_h2d") as wait:
+            issuer.wait(n)
+        wait_s = wait.s
 
 
 @dataclasses.dataclass
@@ -509,6 +517,9 @@ class StreamingExecutor:
         # column's structure) -- warm run_query calls skip re-deriving both
         self._query_schedules: dict[tuple, tuple] = {}
         self._query_traffic: dict[str, tuple[int, int]] = {}
+        # column set -> (order, decisions) of its latest plan, to count
+        # plan changes (obs counter ``plan_changes``)
+        self._last_plans: dict[frozenset, tuple] = {}
         # measured (transfer_s, decode_s) per column from the latest run --
         # an ALIAS of the cost model's store (one source of truth)
         self.timings: dict[str, tuple[float, float]] = self.cost_model.measured
@@ -778,24 +789,38 @@ class StreamingExecutor:
         ``planner.plan_execution``.
         """
         names = list(self._encoded) if names is None else list(names)
-        profiles = {n: self.column_profile(n) for n in names}
         # an explicit policy always wins; pipeline=False only downgrades the
         # constructor DEFAULT to submission order
         if policy is not None:
             pol = policy
         else:
             pol = "fifo" if not self.pipeline else self.policy
-        ep = planner_mod.plan_execution(
-            profiles, self.cost_model, policy=pol,
-            chunk_bytes=(self.chunk_bytes if chunk_bytes is self._DEFAULTS
-                         else chunk_bytes),
-            chunk_decode=(self.chunk_decode if chunk_decode is None
-                          else chunk_decode),
-            window=self.prefetch_chunks if window is None else window,
-            batch_columns=self.batch_columns, fused_columns=fused_columns)
-        if order is not None:
-            ep = dataclasses.replace(ep, order=tuple(order), policy="explicit")
+        with obs.span("zipflow.plan", policy=pol):
+            profiles = {n: self.column_profile(n) for n in names}
+            ep = planner_mod.plan_execution(
+                profiles, self.cost_model, policy=pol,
+                chunk_bytes=(self.chunk_bytes if chunk_bytes is self._DEFAULTS
+                             else chunk_bytes),
+                chunk_decode=(self.chunk_decode if chunk_decode is None
+                              else chunk_decode),
+                window=self.prefetch_chunks if window is None else window,
+                batch_columns=self.batch_columns, fused_columns=fused_columns)
+            if order is not None:
+                ep = dataclasses.replace(ep, order=tuple(order),
+                                         policy="explicit")
+            self._count_plan(names, ep)
         return ep
+
+    def _count_plan(self, names: Sequence[str], ep: ExecutionPlan) -> None:
+        """Count a plan, and a change where its order or a column's decision
+        differs from the previous plan over the same column set."""
+        key = (ep.order, tuple(
+            (d.name, d.chunk_bytes, d.n_chunks, d.decode_mode, d.fused)
+            for _, d in sorted(ep.decisions.items())))
+        cols = frozenset(names)
+        if self._last_plans.get(cols, key) != key:
+            obs.inc("plan_changes")
+        self._last_plans[cols] = key
 
     # --------------------------------------------------------------------- run
     def run(self, encs: dict[str, plan_mod.Encoded] | None = None,
@@ -830,6 +855,12 @@ class StreamingExecutor:
             names = list(encs)
         else:
             names = list(self._encoded)
+        with obs.root("zipflow.stream", columns=len(names)):
+            return self._run(names, order, plan, preempt, on_ready, device,
+                             async_dispatch)
+
+    def _run(self, names, order, plan, preempt, on_ready, device,
+             async_dispatch) -> dict[str, ColumnExec]:
         if plan is None:
             plan = self.plan(names, order=order)
         elif order is not None:
@@ -900,44 +931,46 @@ class StreamingExecutor:
         col_end: dict[str, int] = {}
         chunk_ends: dict[str, list[int]] = {}
         for name in order:
-            ops = plan_mod.host_operands(self._encoded[name])
-            sched = scheds[name]
-            cols: dict[str, list] = {}
-            staged[name] = cols
-            if sched is None:
-                for k, v in ops.items():
-                    pieces = split_chunks(np.asarray(v),
-                                          decisions[name].chunk_bytes)
-                    cols[k] = [None] * len(pieces)
-                    for i, piece in enumerate(pieces):
-                        items.append((name, cols[k], i, piece))
+            with obs.span("zipflow.stage", column=name):
+                ops = plan_mod.host_operands(self._encoded[name])
+                sched = scheds[name]
+                cols: dict[str, list] = {}
+                staged[name] = cols
+                if sched is None:
+                    for k, v in ops.items():
+                        pieces = split_chunks(np.asarray(v),
+                                              decisions[name].chunk_bytes)
+                        cols[k] = [None] * len(pieces)
+                        for i, piece in enumerate(pieces):
+                            items.append((name, cols[k], i, piece))
+                            acq.append(False)
+                            rel.append(False)
+                else:
+                    for k in sched.whole:
+                        cols[k] = [None]
+                        src = sched.host_push.get(k)
+                        items.append((name, cols[k], 0, np.asarray(ops[k])
+                                      if src is None else src))
                         acq.append(False)
                         rel.append(False)
-            else:
-                for k in sched.whole:
-                    cols[k] = [None]
-                    src = sched.host_push.get(k)
-                    items.append((name, cols[k], 0,
-                                  np.asarray(ops[k]) if src is None else src))
-                    acq.append(False)
-                    rel.append(False)
-                ends = []
-                for i in range(sched.n_chunks):
-                    first = len(items)
-                    for k in sched.slices:
-                        # group-path leaves may slice off axis 0 (ANS stripes
-                        # hand each span its own row-capped column block)
-                        cols.setdefault(k, [None] * sched.n_chunks)
-                        piece = sched.piece(np.asarray(ops[k]), k, i)
-                        items.append((name, cols[k], i, piece))
-                        acq.append(False)
-                        rel.append(False)
-                    if len(items) > first:   # one staging slot per chunk
-                        acq[first] = True
-                        rel[-1] = True
-                    ends.append(len(items))
-                chunk_ends[name] = ends
-            col_end[name] = len(items)
+                    ends = []
+                    for i in range(sched.n_chunks):
+                        first = len(items)
+                        for k in sched.slices:
+                            # group-path leaves may slice off axis 0 (ANS
+                            # stripes hand each span its own row-capped
+                            # column block)
+                            cols.setdefault(k, [None] * sched.n_chunks)
+                            piece = sched.piece(np.asarray(ops[k]), k, i)
+                            items.append((name, cols[k], i, piece))
+                            acq.append(False)
+                            rel.append(False)
+                        if len(items) > first:   # one staging slot per chunk
+                            acq[first] = True
+                            rel[-1] = True
+                        ends.append(len(items))
+                    chunk_ends[name] = ends
+                col_end[name] = len(items)
 
         # decode units.  Per-chunk columns are singleton units (their launches
         # are already split along the chunk axis); *consecutive-in-order*
@@ -996,43 +1029,46 @@ class StreamingExecutor:
             last_end = max(leg.col_end[m] for m in members)
             issuer.advance(last_end + window)   # keep the link busy ahead of decode
             wait_s = (yield ("need", last_end)) or 0.0
-            t0 = time.perf_counter()
-            bufs_per_member = []
-            for m in members:
-                chunks = leg.staged[m]
-                bufs = {k: (pieces[0] if len(pieces) == 1
-                            else jnp.concatenate(pieces, axis=0))
-                        for k, pieces in chunks.items()}
-                bufs_per_member.append(bufs)
-            for bufs in bufs_per_member:
-                jax.block_until_ready(list(bufs.values()))
-            t1 = time.perf_counter()
+            # joining a multi-piece column is staging, not a transfer wait;
+            # the residual covers both, as it always has
+            with obs.span("zipflow.stage", column=members[0]) as join:
+                bufs_per_member = []
+                for m in members:
+                    chunks = leg.staged[m]
+                    bufs = {k: (pieces[0] if len(pieces) == 1
+                                else jnp.concatenate(pieces, axis=0))
+                            for k, pieces in chunks.items()}
+                    bufs_per_member.append(bufs)
+            with obs.span("zipflow.wait_h2d", column=members[0],
+                          chunk=0) as wait:
+                for bufs in bufs_per_member:
+                    jax.block_until_ready(list(bufs.values()))
             issuer.consumed(last_end)
-            residual_wait = (wait_s + (t1 - t0)) / len(members)
-            if len(members) > 1:
-                cold = prog.batched_calls == 0
-                stacked = {k: jnp.stack([b[k] for b in bufs_per_member])
-                           for k in bufs_per_member[0]}
-                out = prog.batched(stacked)
+            residual_wait = (wait_s + join.s + wait.s) / len(members)
+            batched = len(members) > 1
+            cold = (prog.batched_calls if batched else prog.calls) == 0
+            with obs.span("zipflow.launch",
+                          program="decode_batched" if batched else "decode",
+                          chunk=0) as launch:
+                if batched:
+                    stacked = {k: jnp.stack([b[k] for b in bufs_per_member])
+                               for k in bufs_per_member[0]}
+                    out = prog.batched(stacked)
+                else:
+                    out = prog(bufs_per_member[0])
+            with obs.span("zipflow.wait_decode", column=members[0]) as done:
                 jax.block_until_ready(out)
-                t2 = time.perf_counter()
-                if cold:      # first call traced+compiled; re-time warm so cached
-                    t1 = time.perf_counter()      # timings model decode, not jit
-                    jax.block_until_ready(prog.batched(stacked))
-                    t2 = time.perf_counter()
-                outs = [out[i] for i in range(len(members))]
-            else:
-                cold = prog.calls == 0
-                outs = [prog(bufs_per_member[0])]
-                jax.block_until_ready(outs[0])
-                t2 = time.perf_counter()
-                if cold:
-                    t1 = time.perf_counter()
-                    jax.block_until_ready(prog(bufs_per_member[0]))
-                    t2 = time.perf_counter()
+            decode_s = launch.s + done.s
+            if cold:      # first call traced+compiled; re-time warm so cached
+                # timings model decode, not jit
+                with obs.span("zipflow.retime", column=members[0]) as again:
+                    jax.block_until_ready(prog.batched(stacked) if batched
+                                          else prog(bufs_per_member[0]))
+                decode_s = again.s
+            outs = [out[i] for i in range(len(members))] if batched else [out]
             # members of one unit share a signature => identical buffer shapes and
             # bytes, so the even decode split is exact, not an approximation
-            decode_s = (t2 - t1) / len(members)
+            decode_s /= len(members)
             siblings = tuple(members) if len(members) > 1 else ()
             for m, arr in zip(members, outs):
                 enc = self._encoded[m]
@@ -1071,31 +1107,34 @@ class StreamingExecutor:
                 preempt()          # chunk boundary: point queries may cut in
             issuer.advance(ends[k] + window)
             residual += (yield ("need", ends[k])) or 0.0
-            t0 = time.perf_counter()
-            if whole_bufs is None:     # issued ahead of chunk 0 by construction
-                whole_bufs = {nm: device_col[nm][0] for nm in sched.whole}
-                jax.block_until_ready(list(whole_bufs.values()))
-            pieces = {nm: device_col[nm][k] for nm in sched.slices}
-            jax.block_until_ready(list(pieces.values()))
-            residual += time.perf_counter() - t0
+            with obs.span("zipflow.wait_h2d", column=name, chunk=k) as wait:
+                if whole_bufs is None:  # issued ahead of chunk 0 by design
+                    whole_bufs = {nm: device_col[nm][0] for nm in sched.whole}
+                    jax.block_until_ready(list(whole_bufs.values()))
+                pieces = {nm: device_col[nm][k] for nm in sched.slices}
+                jax.block_until_ready(list(pieces.values()))
+            residual += wait.s
             prog = self.cache.get_chunk(graph, sched.out_sizes[k])
             cold = cold or prog.calls == 0
             bufs = {**whole_bufs, **pieces}
             start = np.int32(sched.out_starts[k])
-            t0 = time.perf_counter()
-            outs.append(prog(bufs, start))       # async launch; k+1 still in flight
-            dispatch += time.perf_counter() - t0
+            with obs.span("zipflow.launch", program="decode_chunk",
+                          chunk=k) as launch:
+                outs.append(prog(bufs, start))   # async; k+1 still in flight
+            dispatch += launch.s
             issuer.consumed(ends[k])             # chunk k's staging slot frees
             launches.append((prog, bufs, start))
-        t0 = time.perf_counter()
-        arr = outs[0] if K == 1 else jnp.concatenate(outs)
-        jax.block_until_ready(arr)
-        dispatch += time.perf_counter() - t0
+        with obs.span("zipflow.wait_decode", column=name) as done:
+            arr = outs[0] if K == 1 else jnp.concatenate(outs)
+            jax.block_until_ready(arr)
+        dispatch += done.s
         if cold:      # first use traced+compiled: re-run warm so cached timings
-            t0 = time.perf_counter()              # model decode, not jit
-            outs2 = [p(b, s) for p, b, s in launches]
-            jax.block_until_ready(outs2[0] if K == 1 else jnp.concatenate(outs2))
-            decode_s = time.perf_counter() - t0
+            # model decode, not jit
+            with obs.span("zipflow.retime", column=name) as again:
+                outs2 = [p(b, s) for p, b, s in launches]
+                jax.block_until_ready(outs2[0] if K == 1
+                                      else jnp.concatenate(outs2))
+            decode_s = again.s
         else:
             decode_s = dispatch
         enc = self._encoded[name]
@@ -1136,42 +1175,47 @@ class StreamingExecutor:
                 preempt()          # span boundary: point queries may cut in
             issuer.advance(ends[k] + window)
             residual += (yield ("need", ends[k])) or 0.0
-            t0 = time.perf_counter()
-            if whole_bufs is None:     # issued ahead of span 0 by construction
-                whole_bufs = {nm: device_col[nm][0] for nm in sched.whole}
-                jax.block_until_ready(list(whole_bufs.values()))
-            pieces = {nm: device_col[nm][k] for nm in sched.slices}
-            jax.block_until_ready(list(pieces.values()))
-            residual += time.perf_counter() - t0
-            t0 = time.perf_counter()
+            with obs.span("zipflow.wait_h2d", column=name, chunk=k) as wait:
+                if whole_bufs is None:  # issued ahead of span 0 by design
+                    whole_bufs = {nm: device_col[nm][0] for nm in sched.whole}
+                    jax.block_until_ready(list(whole_bufs.values()))
+                pieces = {nm: device_col[nm][k] for nm in sched.slices}
+                jax.block_until_ready(list(pieces.values()))
+            residual += wait.s
             if k == 0 and pro_prog is not None:
                 cold = cold or pro_prog.calls == 0
-                resident = pro_prog(whole_bufs)    # async one-shot prologue
+                with obs.span("zipflow.launch", program="decode_prologue",
+                              chunk=0) as launch:
+                    resident = pro_prog(whole_bufs)  # async one-shot prologue
+                dispatch += launch.s
             prog = self.cache.get_group_chunk(graph, sched.g_sizes[k],
                                               sched.pad_sizes[k])
             cold = cold or prog.calls == 0
             bufs = {**whole_bufs, **resident, **pieces}
             args = (np.int32(sched.out_starts[k]), np.int32(sched.g_starts[k]),
                     np.int32(sched.out_sizes[k]))
-            outs.append(prog(bufs, *args))   # async launch; k+1 still in flight
-            dispatch += time.perf_counter() - t0
+            with obs.span("zipflow.launch", program="decode_span",
+                          chunk=k) as launch:
+                outs.append(prog(bufs, *args))  # async; k+1 still in flight
+            dispatch += launch.s
             issuer.consumed(ends[k])         # span k's staging slot frees
             launches.append((prog, bufs, args))
-        t0 = time.perf_counter()
-        trimmed = [o if int(p) == int(s) else o[:int(s)]
-                   for o, p, s in zip(outs, sched.pad_sizes, sched.out_sizes)]
-        arr = trimmed[0] if K == 1 else jnp.concatenate(trimmed)
-        jax.block_until_ready(arr)
-        dispatch += time.perf_counter() - t0
+        with obs.span("zipflow.wait_decode", column=name) as done:
+            trimmed = [o if int(p) == int(s) else o[:int(s)] for o, p, s
+                       in zip(outs, sched.pad_sizes, sched.out_sizes)]
+            arr = trimmed[0] if K == 1 else jnp.concatenate(trimmed)
+            jax.block_until_ready(arr)
+        dispatch += done.s
         if cold:      # first use traced+compiled: re-run warm so cached timings
-            t0 = time.perf_counter()              # model decode, not jit
-            res2 = pro_prog(whole_bufs) if pro_prog is not None else {}
-            outs2 = [p({**b, **res2}, *a) for p, b, a in launches]
-            outs2 = [o if int(pd) == int(s) else o[:int(s)] for o, pd, s
-                     in zip(outs2, sched.pad_sizes, sched.out_sizes)]
-            jax.block_until_ready(outs2[0] if K == 1
-                                  else jnp.concatenate(outs2))
-            decode_s = time.perf_counter() - t0
+            # model decode, not jit
+            with obs.span("zipflow.retime", column=name) as again:
+                res2 = pro_prog(whole_bufs) if pro_prog is not None else {}
+                outs2 = [p({**b, **res2}, *a) for p, b, a in launches]
+                outs2 = [o if int(pd) == int(s) else o[:int(s)] for o, pd, s
+                         in zip(outs2, sched.pad_sizes, sched.out_sizes)]
+                jax.block_until_ready(outs2[0] if K == 1
+                                      else jnp.concatenate(outs2))
+            decode_s = again.s
         else:
             decode_s = dispatch
         enc = self._encoded[name]
@@ -1583,6 +1627,10 @@ class StreamingExecutor:
         ``max(1, window - 1)``.  Measured selectivity (the Reduce count lane)
         feeds the cost model's per-signature EWMA for future fused-vs-
         materialize planning."""
+        with obs.root("zipflow.query", query=fq.qplan.name):
+            return self._run_query(fq, encs, chunk_bytes, window)
+
+    def _run_query(self, fq, encs, chunk_bytes, window) -> "QueryExec":
         from repro.core import fusion
         from repro.core.ir import query_chunk_layout
 
@@ -1640,15 +1688,19 @@ class StreamingExecutor:
         K = len(out_starts)
 
         t_issue = 0.0
+        qname = fq.qplan.name
+        scan = obs.current_scan()
 
         def put_group(pieces: dict[str, np.ndarray]) -> dict[str, jnp.ndarray]:
             # ONE batched device_put per staging group: per-call dispatch
             # overhead, not bytes, dominates small-buffer H2D
             nonlocal t_issue
-            t0 = time.perf_counter()
             keys = list(pieces)
-            outs = jax.device_put([pieces[nm] for nm in keys])  # async H2D
-            t_issue += time.perf_counter() - t0
+            nbytes = sum(int(p.nbytes) for p in pieces.values())
+            with obs.span("zipflow.put", column=qname, bytes=nbytes,
+                          scan=scan) as put:
+                outs = jax.device_put([pieces[nm] for nm in keys])  # async
+            t_issue += put.s
             return dict(zip(keys, outs))
 
         whole_bufs = put_group({nm: np.asarray(ops[nm]) for nm in whole_names})
@@ -1661,10 +1713,10 @@ class StreamingExecutor:
         def issue_upto(m: int) -> None:
             nonlocal next_issue
             while next_issue < min(m, K):
-                sl = host_slices[next_issue]
-                device_pieces[next_issue] = put_group(
-                    {nm: np.asarray(ops[nm])[lo:hi]
-                     for nm, (lo, hi) in sl.items()})
+                with obs.span("zipflow.stage", column=qname, chunk=next_issue):
+                    pieces = {nm: np.asarray(ops[nm])[lo:hi] for nm, (lo, hi)
+                              in host_slices[next_issue].items()}
+                device_pieces[next_issue] = put_group(pieces)
                 next_issue += 1
 
         residual = 0.0
@@ -1674,65 +1726,68 @@ class StreamingExecutor:
         acc = None
         for k in range(K):
             issue_upto(k + eff)
-            t0 = time.perf_counter()
-            if k == 0:
-                jax.block_until_ready(list(whole_bufs.values()))
-            pieces = device_pieces[k]
-            jax.block_until_ready(list(pieces.values()))
-            residual += time.perf_counter() - t0
+            with obs.span("zipflow.wait_h2d", column=qname, chunk=k) as wait:
+                if k == 0:
+                    jax.block_until_ready(list(whole_bufs.values()))
+                pieces = device_pieces[k]
+                jax.block_until_ready(list(pieces.values()))
+            residual += wait.s
             prog = self.cache.get_query_chunk(graph, out_sizes[k])
             cold = cold or prog.calls == 0
             bufs = {**whole_bufs, **res_bufs, **pieces}
             start = np.int32(out_starts[k])
-            t0 = time.perf_counter()
-            part = prog(bufs, start)          # async launch; k+1.. in flight
-            acc = part if acc is None else acc + part
-            dispatch += time.perf_counter() - t0
+            with obs.span("zipflow.launch", program="query_chunk",
+                          chunk=k) as launch:
+                part = prog(bufs, start)      # async launch; k+1.. in flight
+                acc = part if acc is None else acc + part
+            dispatch += launch.s
             launches.append((prog, bufs, start))
-        t0 = time.perf_counter()
-        jax.block_until_ready(acc)
-        dispatch += time.perf_counter() - t0
+        with obs.span("zipflow.wait_decode", column=qname) as done:
+            jax.block_until_ready(acc)
+        dispatch += done.s
         if cold:      # first use traced+compiled: re-run warm so timings model
-            t0 = time.perf_counter()               # the fused decode, not jit
-            acc2 = None
-            for p, b, s in launches:
-                part = p(b, s)
-                acc2 = part if acc2 is None else acc2 + part
-            jax.block_until_ready(acc2)
-            decode_s = time.perf_counter() - t0
+            # the fused decode, not jit
+            with obs.span("zipflow.retime", column=qname) as again:
+                acc2 = None
+                for p, b, s in launches:
+                    part = p(b, s)
+                    acc2 = part if acc2 is None else acc2 + part
+                jax.block_until_ready(acc2)
+            decode_s = again.s
             acc = acc2
         else:
             decode_s = dispatch
         transfer_s = t_issue + residual
 
-        # acc is tiny (lanes x segments): one D2H pull serves selectivity and
-        # the finalized result without extra device slicing round-trips
-        acc_np = np.asarray(acc)
-        sel = float(fq.selectivity(acc_np))
-        for c in fq.fused_cols:
-            if c not in self.cost_model.profiles and encs and c in encs:
-                from repro.core.compiler import build_graph
-                self.cost_model.register(
-                    profile_from(c, encs[c], build_graph(encs[c])))
-            if c in self.cost_model.profiles:
-                self.cost_model.observe_selectivity(c, sel)
-        traffic = self._query_traffic.get(graph.signature)
-        if traffic is None:
-            all_bufs = {**ops, **res_bufs}
-            traffic = (fusion.hbm_traffic_bytes(graph.stages, all_bufs),
-                       fusion.hbm_traffic_bytes(fq.prefuse_stages, all_bufs))
-            self._query_traffic[graph.signature] = traffic
-        compressed = sum(int(np.asarray(ops[b.name]).nbytes)
-                         for b in graph.buffers)
-        plain = (sum(int(encs[c].plain_nbytes) for c in fq.fused_cols)
-                 if encs else 0)
-        return QueryExec(
-            name=fq.qplan.name, result=fq.finalize(acc_np), acc=acc,
-            transfer_s=transfer_s, decode_s=decode_s,
-            n_chunks=K, decode_launches=K, selectivity=sel,
-            compressed_bytes=compressed, plain_bytes=plain,
-            traffic_bytes=traffic[0], prefuse_traffic_bytes=traffic[1],
-            resident=resident_execs)
+        with obs.span("zipflow.finalize", query=qname):
+            # acc is tiny (lanes x segments): one D2H pull serves selectivity
+            # and the finalized result without extra device slicing round-trips
+            acc_np = np.asarray(acc)
+            sel = float(fq.selectivity(acc_np))
+            for c in fq.fused_cols:
+                if c not in self.cost_model.profiles and encs and c in encs:
+                    from repro.core.compiler import build_graph
+                    self.cost_model.register(
+                        profile_from(c, encs[c], build_graph(encs[c])))
+                if c in self.cost_model.profiles:
+                    self.cost_model.observe_selectivity(c, sel)
+            traffic = self._query_traffic.get(graph.signature)
+            if traffic is None:
+                all_bufs = {**ops, **res_bufs}
+                traffic = (fusion.hbm_traffic_bytes(graph.stages, all_bufs),
+                           fusion.hbm_traffic_bytes(fq.prefuse_stages, all_bufs))
+                self._query_traffic[graph.signature] = traffic
+            compressed = sum(int(np.asarray(ops[b.name]).nbytes)
+                             for b in graph.buffers)
+            plain = (sum(int(encs[c].plain_nbytes) for c in fq.fused_cols)
+                     if encs else 0)
+            return QueryExec(
+                name=fq.qplan.name, result=fq.finalize(acc_np), acc=acc,
+                transfer_s=transfer_s, decode_s=decode_s,
+                n_chunks=K, decode_launches=K, selectivity=sel,
+                compressed_bytes=compressed, plain_bytes=plain,
+                traffic_bytes=traffic[0], prefuse_traffic_bytes=traffic[1],
+                resident=resident_execs)
 
     def unregister(self, name: str) -> None:
         """Drop one registered blob's per-column state (profile, schedules,
@@ -1744,6 +1799,8 @@ class StreamingExecutor:
         for store in (self._chunk_counts, self._schedules):
             for key in [k for k in store if k[0] == name]:
                 store.pop(key)
+        for cols in [c for c in self._last_plans if name in c]:
+            self._last_plans.pop(cols)
         self.cost_model.forget(name)
 
     def run_one(self, enc: plan_mod.Encoded, name: str = "_single") -> jnp.ndarray:

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import compiler, plan as plan_mod
 from repro.core.executor import ColumnExec, StreamingExecutor
 from repro.core.plan import Plan, make_plan
@@ -281,38 +282,39 @@ class ColumnPipeline:
         key = qplan.digest()
         cfg = self._query_cfg.get(key)
         if cfg is None:
-            ep = self.query_plan(qplan)     # registers profiles for all cols
-            if isinstance(self.chunk_bytes, int):
-                cb = self.chunk_bytes       # fixed size: user override
-            else:
-                from repro.core.costmodel import serial_host
+            with obs.span("zipflow.plan", policy=self.policy):
+                ep = self.query_plan(qplan)  # registers profiles for all cols
+                if isinstance(self.chunk_bytes, int):
+                    cb = self.chunk_bytes       # fixed size: user override
+                else:
+                    from repro.core.costmodel import serial_host
 
-                cm = self.executor.cost_model
-                t_tr = d_fused = oh = 0.0
-                for c in fq.fused_cols:
-                    t_tr += cm.predict(c)[0]
-                    d_fused += cm.fused_decode_s(c)
-                    oh = max(oh, cm.launch_overhead_s(c))
-                best_k, best_t = 1, None
-                for k in (1, 2, 4, 8):
-                    if serial_host():
-                        # one resource: no transfer/decode overlap, chunking
-                        # only buys launch overhead
-                        mk = t_tr + d_fused + (k - 1) * oh
-                    else:
-                        mk = scheduler.simulate_stream(
-                            [scheduler.Job(qplan.name, t_tr, d_fused)],
-                            [scheduler.ChunkInfo(n_chunks=k,
-                                                 chunk_decode=k > 1,
-                                                 launch_overhead_s=oh)],
-                            window=ep.window)
-                    if best_t is None or mk < best_t - 1e-12:
-                        best_k, best_t = k, mk
-                comp = sum(self._encoded[c].compressed_nbytes
-                           for c in fq.fused_cols)
-                cb = None if best_k == 1 else -(-comp // best_k)
-            cfg = (ep.window, cb)
-            self._query_cfg[key] = cfg
+                    cm = self.executor.cost_model
+                    t_tr = d_fused = oh = 0.0
+                    for c in fq.fused_cols:
+                        t_tr += cm.predict(c)[0]
+                        d_fused += cm.fused_decode_s(c)
+                        oh = max(oh, cm.launch_overhead_s(c))
+                    best_k, best_t = 1, None
+                    for k in (1, 2, 4, 8):
+                        if serial_host():
+                            # one resource: no transfer/decode overlap,
+                            # chunking only buys launch overhead
+                            mk = t_tr + d_fused + (k - 1) * oh
+                        else:
+                            mk = scheduler.simulate_stream(
+                                [scheduler.Job(qplan.name, t_tr, d_fused)],
+                                [scheduler.ChunkInfo(n_chunks=k,
+                                                     chunk_decode=k > 1,
+                                                     launch_overhead_s=oh)],
+                                window=ep.window)
+                        if best_t is None or mk < best_t - 1e-12:
+                            best_k, best_t = k, mk
+                    comp = sum(self._encoded[c].compressed_nbytes
+                               for c in fq.fused_cols)
+                    cb = None if best_k == 1 else -(-comp // best_k)
+                cfg = (ep.window, cb)
+                self._query_cfg[key] = cfg
         win, cb = cfg
         if window is not None:
             win = window
