@@ -36,7 +36,8 @@ from repro.core import plan as plan_mod
 from repro.core.geometry import Geometry, chip as chip_spec, native_config, resolve_chip
 from repro.core.ir import (DecodeGraph, element_chunk_layout, group_chunk_layout,
                            query_chunk_layout)
-from repro.core.patterns import Aux, Ctx, GroupParallel, NonParallel, Stage
+from repro.core.patterns import (Aux, Ctx, GroupParallel, NonParallel, Stage,
+                                 group_ids)
 
 
 def _run_stage(st: Stage, bufs: dict[str, jnp.ndarray], backend: str,
@@ -347,13 +348,16 @@ def compile_group_chunk_graph(graph: DecodeGraph, g_size: int, pad_elems: int,
     """Compile the per-span variant of a group-chunkable graph.
 
     The group stage re-evaluates its closures at the span's GLOBAL output
-    indices: a Group-Parallel span searches the whole-resident presum (so group
-    id and in-group position are exactly the whole-column values) and gathers
-    sliced value leaves at span-local offsets; a Non-Parallel span lockstep-
-    decodes its own column slice of the stripe.  Trailing Fully-Parallel stages
-    use the element path's addressing.  Bitwise equality with whole-column
-    decode holds by construction: same closures, same global indices, exact
-    group-aligned slices."""
+    indices: a Group-Parallel span takes its group ids as ``g_start`` plus
+    ``group_ids`` over its own window of the whole-resident presum, rebased to
+    ``out_start`` (exactly the whole-column
+    ``searchsorted(presum, i, 'right') - 1`` for its valid lanes; padding lanes
+    keep the last valid group), reads in-group positions from that presum and
+    gathers sliced value leaves at span-local offsets; a Non-Parallel span
+    lockstep-decodes its own column slice of the stripe.  Trailing
+    Fully-Parallel stages use the element path's addressing.  Bitwise equality
+    with whole-column decode holds by construction: same closures, same global
+    indices, exact group-aligned slices."""
     layout = group_chunk_layout(graph)
     if layout is None:
         raise ValueError(f"graph {graph.nesting!r} is not group-chunkable")
@@ -371,8 +375,10 @@ def compile_group_chunk_graph(graph: DecodeGraph, g_size: int, pad_elems: int,
         out_idx = out_start + jnp.minimum(j, jnp.maximum(n_valid - 1, 0))
         if isinstance(gst, GroupParallel):
             presum = env[gst.presum]
-            g = jnp.searchsorted(presum, out_idx, side="right").astype(
-                jnp.int32) - 1
+            # the span starts at whole group g_start, so its presum window
+            # rebased to out_start is a local presum starting at 0
+            local = jax.lax.dynamic_slice(presum, (g_start,), (g_size + 1,))
+            g = g_start + group_ids(local - out_start, pad_elems, n_valid)
             pos = out_idx - presum[g]
             # span-time value grafts: re-evaluate the producer closure at the
             # span's global group indices over its sliced primary leaf -- the

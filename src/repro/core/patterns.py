@@ -144,6 +144,26 @@ class FullyParallel(Stage):
         return self.fn(ctx, *[bufs[k] for k in self.inputs]).astype(self.out_dtype)
 
 
+def group_ids(presum: jnp.ndarray, n_out: int, n_valid=None) -> jnp.ndarray:
+    """Owning group of each output position ``i`` in ``[0, n_out)``:
+    ``searchsorted(presum, i, side="right") - 1`` for a sorted ``presum`` with
+    ``presum[0] == 0``, empty groups included.
+
+    Every group after the first marks the position it starts at, and ``g[i]``
+    counts the marks at or before ``i``: one scatter of ``n_groups`` marks and one
+    prefix sum, where a binary search gathers ``presum`` once per level for every
+    output.  Empty groups put several marks on one position.  Starts at or past
+    ``n_valid`` (default ``n_out``) are dropped, so positions from ``n_valid`` on
+    keep the last valid group.
+    """
+    starts = presum[1:]
+    if n_valid is not None:
+        starts = jnp.where(starts < n_valid, starts, n_out)
+    marks = jnp.zeros((n_out,), jnp.int32).at[starts].add(
+        1, mode="drop", indices_are_sorted=True)
+    return jnp.cumsum(marks, dtype=jnp.int32)
+
+
 @dataclasses.dataclass
 class GroupParallel(Stage):
     """Balanced 1->N expansion (paper §4 'Scheduling Group-Parallel for Load Balance').
@@ -153,9 +173,11 @@ class GroupParallel(Stage):
              out[i] = map_fn(ctx, value_fn(g, value-blocks...), pos, g)
 
     ``presum`` is the inclusive-prefix-sum of group counts with a leading 0
-    (len n_groups+1) -- the paper's "one-time data scan".  ``value_fn`` materializes the
-    per-group payload; absorbing a preceding Fully-Parallel stage here is exactly the
-    paper's Fig. 7(c) fusion of bit-packing into the RLE kernel.
+    (len n_groups+1) -- the paper's "one-time data scan".  ``g`` is computed by
+    ``group_ids``, a prefix sum of group-start marks that equals the
+    ``searchsorted`` above exactly.  ``value_fn`` materializes the per-group payload;
+    absorbing a preceding Fully-Parallel stage here is exactly the paper's
+    Fig. 7(c) fusion of bit-packing into the RLE kernel.
     """
 
     presum: str
@@ -183,7 +205,7 @@ class GroupParallel(Stage):
     def run_jnp(self, bufs: dict[str, jnp.ndarray]) -> jnp.ndarray:
         presum = bufs[self.presum]
         i = jnp.arange(self.n_out, dtype=jnp.int32)
-        g = jnp.searchsorted(presum, i, side="right").astype(jnp.int32) - 1
+        g = group_ids(presum, self.n_out)
         pos = i - presum[g]
         ctx = Ctx(out_idx=i, starts=tuple(0 for _ in self.value_inputs))
         gval = self.value_fn(ctx, g, *[bufs[k] for k in self.value_inputs])

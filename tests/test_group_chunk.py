@@ -12,18 +12,25 @@ columns; (5) cost-model persistence round-trips scales + per-signature timings.
 """
 import dataclasses
 import json
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import plan as P
-from repro.core.compiler import ProgramCache
+from repro.core.compiler import (ProgramCache, build_graph, compile_decoder,
+                                 compile_graph, compile_group_chunk_graph,
+                                 compile_group_prologue, device_buffers)
 from repro.core.costmodel import (ColumnProfile, CostModel,
                                   aligned_chunk_elems, groups_per_chunk)
 from repro.core.executor import StreamingExecutor
 from repro.core.geometry import native_subtile
 from repro.core.ir import CHUNK_GROUP, group_chunk_layout
+from repro.core.patterns import group_ids
 from repro.core.planner import CHUNK, plan_execution
+from repro.kernels.ref import expand_ref
 
 mp = P.make_plan
 
@@ -101,6 +108,170 @@ def test_group_chunk_programs_shared_across_columns(rng):
     # 3 columns x K spans hit <= 4 cache entries
     assert cache.stats["misses"] <= 4
     assert cache.stats["hits"] >= 2 * (results["c0"].decode_launches - 2)
+
+
+# ------------------------------------------- group ids without a search
+
+# run lengths of a Group-Parallel stage; zeros are empty groups
+GROUP_COUNTS = {
+    "one_group": [37],
+    "all_size_1": [1] * 64,
+    "empty_lead_inner_trail": [0, 0, 3, 0, 0, 5, 1, 0, 2, 0, 0],
+    "random_0_7": np.random.default_rng(7).integers(0, 8, 300).tolist(),
+}
+
+
+def _rle_blob(counts) -> P.Encoded:
+    """An RLE blob with exactly these run lengths, empty runs kept (the
+    encoder emits maximal runs only); every run's value is distinct, so a
+    wrong group id shows in the output."""
+    counts = np.asarray(counts, np.int32)
+    presum = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
+    values = np.arange(counts.size, dtype=np.int32) * 7 + 3
+    return P.Encoded(codec="rle", meta={"n_groups": int(counts.size),
+                                        "group_presum": presum},
+                     buffers={"values": values, "counts": counts},
+                     children={}, n=int(presum[-1]), dtype=np.dtype(np.int32))
+
+
+def _searched_groups(blob: P.Encoded) -> np.ndarray:
+    presum = blob.meta["group_presum"]
+    return np.searchsorted(presum, np.arange(blob.n), side="right") - 1
+
+
+@pytest.mark.parametrize("case", sorted(GROUP_COUNTS))
+def test_group_ids_equal_search(case):
+    """The prefix sum of run-start marks is the search's group id, and
+    expands exactly as the independent oracle does."""
+    blob = _rle_blob(GROUP_COUNTS[case])
+    presum = jnp.asarray(blob.meta["group_presum"], jnp.int32)
+    want = _searched_groups(blob)
+    searched = jnp.searchsorted(presum, jnp.arange(blob.n, dtype=jnp.int32),
+                                side="right") - 1
+    np.testing.assert_array_equal(np.asarray(searched), want)
+    np.testing.assert_array_equal(np.asarray(group_ids(presum, blob.n)), want)
+    values = blob.buffers["values"]
+    np.testing.assert_array_equal(
+        np.asarray(expand_ref(presum, jnp.asarray(values), blob.n)),
+        values[want])
+
+
+@pytest.mark.parametrize("backend", ["jnp", "baseline"])
+@pytest.mark.parametrize("case", sorted(GROUP_COUNTS))
+def test_group_parallel_whole_column_matches_oracle(case, backend):
+    """``GroupParallel.run_jnp`` (whole-column decode) equals the search
+    oracle and the NumPy decode, empty groups included."""
+    blob = _rle_blob(GROUP_COUNTS[case])
+    got = np.asarray(compile_decoder(blob, backend=backend)(
+        device_buffers(blob)))
+    np.testing.assert_array_equal(got, blob.buffers["values"][
+        _searched_groups(blob)])
+    np.testing.assert_array_equal(got, P.decode_np(blob))
+
+
+# a column of one group is never group-chunked (``group_chunk_layout``), so
+# the span path takes the other counts, and single-group spans besides
+@pytest.mark.parametrize("case, body", [
+    ("all_size_1", 22), ("empty_lead_inner_trail", 4), ("random_0_7", 101),
+    ("random_0_7", 7), ("empty_lead_inner_trail", 1)])
+def test_group_chunk_spans_match_oracle(case, body):
+    """Span programs over whole groups: body spans padded past their valid
+    lanes and an uneven tail span decode the search oracle's values, and
+    every padding lane repeats the span's last valid element."""
+    blob = _rle_blob(GROUP_COUNTS[case])
+    graph = build_graph(blob)
+    layout = group_chunk_layout(graph)
+    assert set(layout.sliced) == {"root.values"}
+    ops = device_buffers(blob)
+    resident = compile_group_prologue(graph).fn(ops)
+    presum = blob.meta["group_presum"]
+    n_groups = len(presum) - 1
+    starts = range(0, n_groups, body)
+    pad = max(int(presum[min(s + body, n_groups)] - presum[s])
+              for s in starts) + 5
+    progs = {}
+    pieces = []
+    for s in starts:
+        size = min(body, n_groups - s)
+        n_valid = int(presum[s + size] - presum[s])
+        pad_elems = pad if size == body else n_valid + 3
+        if (size, pad_elems) not in progs:
+            progs[size, pad_elems] = compile_group_chunk_graph(
+                graph, size, pad_elems)
+        prog = progs[size, pad_elems]
+        bufs = {**ops, **resident,
+                "root.values": ops["root.values"][s:s + size]}
+        out = np.asarray(prog(bufs, np.int32(presum[s]), np.int32(s),
+                              np.int32(n_valid)))
+        assert out.shape == (pad_elems,) and n_valid < pad_elems
+        if n_valid:
+            assert (out[n_valid:] == out[n_valid - 1]).all()
+        pieces.append(out[:n_valid])
+    assert size < body or body == 1              # an uneven tail span
+    got = np.concatenate(pieces)
+    np.testing.assert_array_equal(got, blob.buffers["values"][
+        _searched_groups(blob)])
+    np.testing.assert_array_equal(got, P.decode_np(blob))
+
+
+def _sparse_keys(rng):
+    """dbgen-style sparse order keys (the first 8 of every 32) and their
+    lineitem repeats of 1-7: the stream's key columns in miniature."""
+    k = np.arange(3_000)
+    okey = ((k // 8) * 32 + k % 8 + 1).astype(np.int32)
+    return okey, np.repeat(okey, rng.integers(1, 8, okey.size))
+
+
+# plan, which of ``_sparse_keys``' columns it encodes, and whether the
+# executor group-chunks it (RLE over a Group-Parallel child decodes whole)
+GP_PLANS = {
+    "rle": (mp("rle"), 1, True),
+    "deltastride": (mp("deltastride"), 0, True),
+    "rle_deltastride": (P.Plan("rle", children={"values": mp("deltastride")}),
+                        1, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GP_PLANS))
+def test_group_parallel_codecs_bitexact(name, rng):
+    """RLE, DeltaStride and RLE{DeltaStride} decode bit-exact against the
+    NumPy decode, whole-column (jnp and baseline) and group-chunked where
+    the executor chunks the plan."""
+    plan, which, chunked = GP_PLANS[name]
+    arr = _sparse_keys(rng)[which]
+    enc = P.encode(plan, arr)
+    for backend in ("jnp", "baseline"):
+        got = compile_decoder(enc, backend=backend)(device_buffers(enc))
+        np.testing.assert_array_equal(np.asarray(got), P.decode_np(enc),
+                                      err_msg=backend)
+    ex = StreamingExecutor(chunk_bytes=1024, chunk_decode=True,
+                           cache=ProgramCache())
+    res = ex.run({"c": enc})["c"]
+    assert res.chunk_decoded == chunked
+    assert res.decode_launches > 2 if chunked else res.decode_launches == 1
+    np.testing.assert_array_equal(np.asarray(res.array), P.decode_np(enc))
+    np.testing.assert_array_equal(np.asarray(res.array), arr)
+
+
+def _has_while(lowered) -> bool:
+    return re.search(r"\bwhile\b", lowered.as_text()) is not None
+
+
+@pytest.mark.parametrize("name", sorted(GP_PLANS))
+def test_group_parallel_programs_have_no_search_loop(name, rng):
+    """The whole-column decode programs of the Group-Parallel codecs hold no
+    loop: a binary search for group ids (a ``while`` of log2(n_groups)
+    levels, each gathering once per output) cannot come back unnoticed."""
+    plan, which, _ = GP_PLANS[name]
+    enc = P.encode(plan, _sparse_keys(rng)[which])
+    prog = compile_graph(build_graph(enc), backend="jnp")
+    args = {k: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype)
+            for k, v in P.host_operands(enc).items()}
+    assert not _has_while(prog.fn.lower(args))
+    # the check sees a search where there is one
+    presum = jax.ShapeDtypeStruct((enc.meta["n_groups"] + 1,), jnp.int32)
+    assert _has_while(jax.jit(lambda p: jnp.searchsorted(
+        p, jnp.arange(enc.n), side="right")).lower(presum))
 
 
 # ------------------------------------------------------------ planner mirror
