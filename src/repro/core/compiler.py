@@ -30,6 +30,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import fusion as fusion_mod
 from repro.core import plan as plan_mod
@@ -229,25 +231,16 @@ class QueryChunkProgram:
         return self.fn(bufs, out_start)
 
 
-def compile_query_chunk_graph(graph: DecodeGraph, chunk_elems: int,
-                              jit: bool = True) -> QueryChunkProgram:
-    """Compile the per-chunk variant of a fused-query (Reduce-terminated) graph.
-
-    Same addressing as ``compile_chunk_graph`` over the Reduce's ITEM axis,
-    plus "row" inputs: decoded resident columns ride whole and are gathered at
-    the chunk's global row indices (start 0)."""
-    layout = query_chunk_layout(graph)
-    if layout is None:
-        raise ValueError(f"graph {graph.nesting!r} is not query-chunkable")
+def _query_chunk_body(graph: DecodeGraph, chunk_elems: int, static0: bool):
+    """``partial_chunk(bufs, out_start, n_valid=None)``: the fused query's
+    partial aggregate over item rows [out_start, out_start + chunk_elems).
+    ``static0`` bakes ``out_start`` in as 0 (a single-chunk program);
+    ``n_valid`` (a traced scalar) marks rows at or past it as padding the
+    Reduce does not count."""
     stages = graph.stages
-    # single-chunk program: the only start ever passed is 0, so bake it in as a
-    # Python int -- every input offset folds to a constant and XLA's gather
-    # simplifier turns ``block[iota - 0]`` into a plain read, where a traced
-    # start forces real gathers through the whole fused body (measurably
-    # slower on CPU)
-    static0 = int(chunk_elems) >= int(stages[-1].n_in)
 
-    def partial_chunk(bufs: dict[str, jnp.ndarray], out_start) -> jnp.ndarray:
+    def partial_chunk(bufs: dict[str, jnp.ndarray], out_start,
+                      n_valid=None) -> jnp.ndarray:
         if static0:
             out_start = 0
         out_idx = out_start + jnp.arange(chunk_elems, dtype=jnp.int32)
@@ -268,16 +261,101 @@ def compile_query_chunk_graph(graph: DecodeGraph, chunk_elems: int,
                     starts.append((out_start * num) // spec.den)
                 else:
                     starts.append((out_start * spec.num) // spec.den)
-            ctx = Ctx(out_idx=out_idx, starts=tuple(starts))
+            ctx = Ctx(out_idx=out_idx, starts=tuple(starts), n_valid=n_valid)
             out = st.fn(ctx, *[env[nm] for nm in st.inputs]).astype(st.out_dtype)
             env[st.out] = out
             produced.add(st.out)
         return out
 
-    fn = _named(partial_chunk, "query_chunk", pattern_of(graph))
+    return partial_chunk
+
+
+def compile_query_chunk_graph(graph: DecodeGraph, chunk_elems: int,
+                              jit: bool = True) -> QueryChunkProgram:
+    """Compile the per-chunk variant of a fused-query (Reduce-terminated) graph.
+
+    Same addressing as ``compile_chunk_graph`` over the Reduce's ITEM axis,
+    plus "row" inputs: decoded resident columns ride whole and are gathered at
+    the chunk's global row indices (start 0)."""
+    if query_chunk_layout(graph) is None:
+        raise ValueError(f"graph {graph.nesting!r} is not query-chunkable")
+    # single-chunk program: the only start ever passed is 0, so bake it in as a
+    # Python int -- every input offset folds to a constant and XLA's gather
+    # simplifier turns ``block[iota - 0]`` into a plain read, where a traced
+    # start forces real gathers through the whole fused body (measurably
+    # slower on CPU)
+    static0 = int(chunk_elems) >= int(graph.stages[-1].n_in)
+    fn = _named(_query_chunk_body(graph, chunk_elems, static0), "query_chunk",
+                pattern_of(graph))
     fn = jax.jit(fn) if jit else fn
     return QueryChunkProgram(fn=fn, graph=graph, chunk_elems=int(chunk_elems),
                              jit=jit)
+
+
+# the mesh's one axis: rows, partitioned by chip
+MESH_AXIS = "chips"
+
+
+@dataclasses.dataclass
+class MeshQueryChunkProgram:
+    """One fused-query chunk launch over a one-axis mesh of chips: a single
+    SPMD program (``shard_map``), compiled once for the mesh, in which chip
+    i evaluates the query over rows [out_start, out_start + chunk_elems) of
+    its own row partition and adds the partial aggregate to its own
+    accumulator lanes.
+
+    ``fn(tiled, whole, out_start, n_valid, acc)``: ``tiled`` holds each tile
+    leaf's chunk pieces and each resident ("row") input, chip i's block i of
+    a global array sharded along the mesh axis; ``whole`` is replicated;
+    ``out_start`` is the chunk's partition-local first row, the same on every
+    chip; ``n_valid`` (one int32 a chip) counts a partition's real rows;
+    ``acc`` holds ``lanes`` float32 a chip (``graph.n_out`` used, the rest
+    zero).  Returns the new accumulator, sharded like ``acc``."""
+
+    fn: Callable[..., jnp.ndarray]
+    graph: DecodeGraph
+    chunk_elems: int
+    devices: tuple
+    lanes: int
+    calls: int = 0
+
+    def __call__(self, tiled: dict[str, jnp.ndarray],
+                 whole: dict[str, jnp.ndarray], out_start, n_valid,
+                 acc) -> jnp.ndarray:
+        self.calls += 1
+        return self.fn(tiled, whole, out_start, n_valid, acc)
+
+
+def mesh_of(devices) -> Mesh:
+    """The one-axis mesh over ``devices``, in partition order."""
+    return Mesh(np.asarray(devices), (MESH_AXIS,))
+
+
+def compile_mesh_query_chunk(graph: DecodeGraph, chunk_elems: int,
+                             devices, lanes: int) -> MeshQueryChunkProgram:
+    """Compile the mesh variant of a fused-query chunk program (see
+    ``MeshQueryChunkProgram``).  Every chip runs the same per-chunk body as
+    ``compile_query_chunk_graph`` with partition-local indices: partitions
+    start on the layout's alignment, so each tile leaf's local word offsets
+    equal its global ones less the partition's first word."""
+    if query_chunk_layout(graph) is None:
+        raise ValueError(f"graph {graph.nesting!r} is not query-chunkable")
+    n_out = int(graph.n_out)
+    if lanes < n_out:
+        raise ValueError(f"{lanes} accumulator lanes hold no {n_out} outputs")
+    body = _query_chunk_body(graph, chunk_elems, static0=False)
+
+    def per_chip(tiled, whole, out_start, n_valid, acc):
+        part = body({**whole, **tiled}, out_start, n_valid[0])
+        return acc + jnp.pad(part, (0, lanes - n_out))
+
+    row, rep = PartitionSpec(MESH_AXIS), PartitionSpec()
+    fn = jax.shard_map(per_chip, mesh=mesh_of(devices),
+                       in_specs=(row, rep, rep, row, row), out_specs=row)
+    fn = jax.jit(_named(fn, "query_chunk_mesh", pattern_of(graph)))
+    return MeshQueryChunkProgram(fn=fn, graph=graph,
+                                 chunk_elems=int(chunk_elems),
+                                 devices=tuple(devices), lanes=int(lanes))
 
 
 # ------------------------------------------------------- group-boundary chunks
@@ -569,6 +647,23 @@ class ProgramCache:
         key = (graph.signature, "qchunk", int(chunk_elems), jit)
         return self._get(key, lambda: compile_query_chunk_graph(
             graph, chunk_elems, jit=jit))
+
+    def get_mesh_query_chunk(self, graph: DecodeGraph, chunk_elems: int,
+                             devices, lanes: int) -> MeshQueryChunkProgram:
+        """Cached mesh fused-query chunk program: one per (structure, chunk
+        size, chips), whatever the number of chips."""
+        key = (graph.signature, "mqchunk", int(chunk_elems),
+               tuple(d.id for d in devices), int(lanes))
+        return self._get(key, lambda: compile_mesh_query_chunk(
+            graph, chunk_elems, devices, lanes))
+
+    def get_combine(self, devices, lanes: int):
+        """Cached combine of the chips' partial aggregates
+        (``core.query.compile_combine``)."""
+        from repro.core.query import compile_combine
+
+        key = ("combine", tuple(d.id for d in devices), int(lanes))
+        return self._get(key, lambda: compile_combine(devices, lanes))
 
     def get_group_chunk(self, graph: DecodeGraph, g_size: int, pad_elems: int,
                         jit: bool = True) -> GroupChunkProgram:

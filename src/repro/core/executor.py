@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from typing import Sequence
 
 import jax
@@ -66,6 +65,9 @@ from repro.core.planner import ExecutionPlan
 # the number of distinct stripe shapes (= jit retraces of the span programs)
 # stays bounded while still skipping most of the max_words padding
 ROW_CAP_QUANTUM = 64
+
+# a span size no column reaches: the schedule of a shard decoded in one span
+ONE_SPAN_BYTES = 1 << 62
 
 
 def split_chunks(arr: np.ndarray, chunk_bytes: int | None) -> list[np.ndarray]:
@@ -148,8 +150,24 @@ class ChunkSchedule:
 # jit trace/compile) happens on the dispatcher thread driving the generator.
 
 # one transfer item: (column name for issue-time accounting, destination
-# staging list, slot index, host piece)
-_TransferItem = tuple  # (str, list, int, np.ndarray)
+# staging list, slot index, host piece -- or a list of pieces put at once)
+_TransferItem = tuple  # (str, list, int, np.ndarray | list[np.ndarray])
+
+
+def _device_id(device) -> int:
+    """The id of ``device``; None means JAX's default device."""
+    return (device if device is not None else jax.devices()[0]).id
+
+
+def _ceil_to(x: int, m: int) -> int:
+    """The least multiple of ``m`` at or above ``x``."""
+    return -(-x // m) * m
+
+
+def _nbytes(piece) -> int:
+    if isinstance(piece, list):
+        return sum(int(p.nbytes) for p in piece)
+    return int(piece.nbytes)
 
 
 class _InlineIssuer:
@@ -159,6 +177,7 @@ class _InlineIssuer:
     def __init__(self, items: list, device, issue_s: dict[str, float]):
         self._items = items
         self._device = device
+        self._dev_id = _device_id(device)
         self._scan = obs.current_scan()
         self.issue_s = issue_s
         self.total = len(items)
@@ -167,8 +186,8 @@ class _InlineIssuer:
     def advance(self, target: int) -> None:
         while self.committed < min(target, self.total):
             name, dest, i, piece = self._items[self.committed]
-            with obs.span("zipflow.put", column=name, bytes=piece.nbytes,
-                          scan=self._scan) as put:
+            with obs.span("zipflow.put", column=name, bytes=_nbytes(piece),
+                          device=self._dev_id, scan=self._scan) as put:
                 dest[i] = jax.device_put(piece, self._device)   # async H2D
             self.issue_s[name] = self.issue_s.get(name, 0.0) + put.s
             self.committed += 1
@@ -204,6 +223,7 @@ class _WorkerIssuer:
                  name: str = "zipflow-xfer", sync: bool = False):
         self._items = items
         self._device = device
+        self._dev_id = _device_id(device)
         self._sync = sync
         # the dispatcher's scan: puts on the worker thread carry its id
         self._scan = obs.current_scan()
@@ -242,8 +262,7 @@ class _WorkerIssuer:
                         while not self._budget.acquire(timeout=0.1):
                             if self._stop:
                                 return
-                    with obs.span("zipflow.put", column=name,
-                                  bytes=piece.nbytes, scan=self._scan) as put:
+                    with self._span(name, piece) as put:
                         buf = jax.device_put(piece, self._device)  # async H2D
                         if self._sync:
                             # D2D copy legs block here so issue_s records
@@ -261,6 +280,14 @@ class _WorkerIssuer:
             with self._cv:
                 self.error = e
                 self._cv.notify_all()
+
+    def _span(self, name: str, piece) -> obs.span:
+        if self._sync:      # a D2D leg: the piece is a decoded device array
+            src = min(d.id for d in piece.devices())
+            return obs.span("zipflow.d2d", column=name, bytes=_nbytes(piece),
+                            src=src, dst=self._dev_id, scan=self._scan)
+        return obs.span("zipflow.put", column=name, bytes=_nbytes(piece),
+                        device=self._dev_id, scan=self._scan)
 
     # ----- dispatcher side
     def advance(self, target: int) -> None:
@@ -434,7 +461,10 @@ class QueryExec:
     ``traffic_bytes`` is the fused graph's modeled HBM traffic (leaf reads +
     the ``n_out`` accumulator lanes); ``prefuse_traffic_bytes`` prices the same
     stage list before operator fusion, where every decoded column and mask
-    round-trips HBM -- the delta is what fusion removed."""
+    round-trips HBM -- the delta is what fusion removed.  On a mesh,
+    ``decode_launches`` counts chunk launches (each one SPMD launch over the
+    chips), ``acc`` is the combined accumulator (padded to
+    ``query.mesh_lanes``) and ``result`` is finalized from it."""
 
     name: str
     result: jnp.ndarray
@@ -449,6 +479,9 @@ class QueryExec:
     traffic_bytes: int
     prefuse_traffic_bytes: int
     resident: dict[str, ColumnExec] = dataclasses.field(default_factory=dict)
+    # row-sharded query: device id -> decode launches that chip ran (its
+    # chunk launches and its resident shards'); empty on one chip
+    device_launches: dict[int, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -517,6 +550,9 @@ class StreamingExecutor:
         # column's structure) -- warm run_query calls skip re-deriving both
         self._query_schedules: dict[tuple, tuple] = {}
         self._query_traffic: dict[str, tuple[int, int]] = {}
+        # row-sharded queries: (resident columns, chips, rows, alignment) ->
+        # partition bounds and the resident columns' mesh plan
+        self._mesh_plans: dict[tuple, tuple] = {}
         # column set -> (order, decisions) of its latest plan, to count
         # plan changes (obs counter ``plan_changes``)
         self._last_plans: dict[frozenset, tuple] = {}
@@ -541,6 +577,8 @@ class StreamingExecutor:
         for store in (self._chunk_counts, self._schedules):
             for key in [k for k in store if k[0] == name]:
                 store.pop(key)
+        for key in [k for k in self._mesh_plans if name in k[0]]:
+            self._mesh_plans.pop(key)
         self.cost_model.forget(name)    # drops profile + measured timings
         prog = compile_blob(enc, backend=self.backend, fuse=self.fuse,
                             chip=self.chip, cache=self.cache)
@@ -755,12 +793,13 @@ class StreamingExecutor:
                        g_lo: int, g_hi: int) -> ChunkSchedule | None:
         """Group-span schedule restricted to ``[g_lo, g_hi)`` (mesh shards).
         Always returns a schedule for group-chunkable columns (``force=True``:
-        a shard needs one even when it fits a single span)."""
+        a shard needs one even when it fits a single span).  ``chunk_bytes``
+        None decodes the shard in one span, as the planner's shard decision
+        (``planner._shard_decision``: one chunk) models it."""
         key = (name, chunk_bytes, (int(g_lo), int(g_hi)))
         if key in self._schedules:
             return self._schedules[key]
-        cb = (planner_mod.DEFAULT_CHUNK_BYTES if chunk_bytes is None
-              else chunk_bytes)
+        cb = ONE_SPAN_BYTES if chunk_bytes is None else chunk_bytes
         sched = self._build_group_schedule(name, cb, g_lo=g_lo, g_hi=g_hi,
                                            force=True)
         self._schedules[key] = sched
@@ -1149,7 +1188,7 @@ class StreamingExecutor:
     def _run_group_chunked(self, name: str, sched: ChunkSchedule,
                            device_col: dict[str, list], ends: list[int],
                            issuer, window: int, preempt=None,
-                           observe: bool = True):
+                           observe: bool = True, pad_to: int | None = None):
         """Group-boundary streaming decode of one column.
 
         The prologue (presum auxes, nested child decodes) launches once over
@@ -1159,7 +1198,12 @@ class StreamingExecutor:
         body shape, trimmed to each span's true size and concatenated on
         device -- bitwise identical to the whole-column result.  Generator
         (see ``_decode_leg``): yields ``("need", n)`` per span, returns the
-        ``ColumnExec``."""
+        ``ColumnExec``.
+
+        ``pad_to`` (a one-span schedule only): the launch produces that many
+        elements and is not trimmed -- a row shard already shaped like its
+        chip's padded partition, with no slice or pad op after it.  Elements
+        past the span repeat its last one."""
         graph = self._graphs[name]
         K = sched.n_chunks
         residual = 0.0
@@ -1188,8 +1232,10 @@ class StreamingExecutor:
                               chunk=0) as launch:
                     resident = pro_prog(whole_bufs)  # async one-shot prologue
                 dispatch += launch.s
-            prog = self.cache.get_group_chunk(graph, sched.g_sizes[k],
-                                              sched.pad_sizes[k])
+            pad_k = sched.pad_sizes[k]
+            if pad_to is not None and K == 1:
+                pad_k = max(int(pad_to), pad_k)
+            prog = self.cache.get_group_chunk(graph, sched.g_sizes[k], pad_k)
             cold = cold or prog.calls == 0
             bufs = {**whole_bufs, **resident, **pieces}
             args = (np.int32(sched.out_starts[k]), np.int32(sched.g_starts[k]),
@@ -1200,10 +1246,14 @@ class StreamingExecutor:
             dispatch += launch.s
             issuer.consumed(ends[k])         # span k's staging slot frees
             launches.append((prog, bufs, args))
+        untrimmed = pad_to is not None and K == 1
         with obs.span("zipflow.wait_decode", column=name) as done:
-            trimmed = [o if int(p) == int(s) else o[:int(s)] for o, p, s
-                       in zip(outs, sched.pad_sizes, sched.out_sizes)]
-            arr = trimmed[0] if K == 1 else jnp.concatenate(trimmed)
+            if untrimmed:
+                arr = outs[0]
+            else:
+                trimmed = [o if int(p) == int(s) else o[:int(s)] for o, p, s
+                           in zip(outs, sched.pad_sizes, sched.out_sizes)]
+                arr = trimmed[0] if K == 1 else jnp.concatenate(trimmed)
             jax.block_until_ready(arr)
         dispatch += done.s
         if cold:      # first use traced+compiled: re-run warm so cached timings
@@ -1211,8 +1261,10 @@ class StreamingExecutor:
             with obs.span("zipflow.retime", column=name) as again:
                 res2 = pro_prog(whole_bufs) if pro_prog is not None else {}
                 outs2 = [p({**b, **res2}, *a) for p, b, a in launches]
-                outs2 = [o if int(pd) == int(s) else o[:int(s)] for o, pd, s
-                         in zip(outs2, sched.pad_sizes, sched.out_sizes)]
+                if not untrimmed:
+                    outs2 = [o if int(pd) == int(s) else o[:int(s)]
+                             for o, pd, s in zip(outs2, sched.pad_sizes,
+                                                 sched.out_sizes)]
                 jax.block_until_ready(outs2[0] if K == 1
                                       else jnp.concatenate(outs2))
             decode_s = again.s
@@ -1266,7 +1318,8 @@ class StreamingExecutor:
         return sched, device_col, ends
 
     def _run_shard(self, column: str, spec, chunk_bytes: int | None,
-                   device, window: int) -> ColumnExec:
+                   device, window: int, pad_to: int | None = None
+                   ) -> ColumnExec:
         """Decode one group-span shard of a registered column on ``device``
         (inline issue -- the sequential mesh path).
 
@@ -1280,13 +1333,15 @@ class StreamingExecutor:
                                                     items, [], [])
         issuer = _InlineIssuer(items, device, {})
         gen = self._run_group_chunked(column, sched, device_col, ends,
-                                      issuer, window, observe=False)
+                                      issuer, window, observe=False,
+                                      pad_to=pad_to)
         rec = _drive_seq(gen, issuer)
         return dataclasses.replace(
             rec, name=planner_mod.shard_name(column, spec.index))
 
     def _device_leg(self, leg: _StagedLeg | None, shard_stage: list,
-                    issuer, window: int, on_ready=None, on_shard=None):
+                    issuer, window: int, on_ready=None, on_shard=None,
+                    pad_to: int | None = None):
         """Combined decode-driver generator for one mesh device: the whole-
         column leg first (plan order), then each group-span shard -- exactly
         the sequence the sequential path executes per device, over ONE shared
@@ -1301,7 +1356,8 @@ class StreamingExecutor:
         recs = []
         for col, spec, sched, device_col, ends in shard_stage:
             rec = yield from self._run_group_chunked(
-                col, sched, device_col, ends, issuer, window, observe=False)
+                col, sched, device_col, ends, issuer, window, observe=False,
+                pad_to=pad_to)
             rec = dataclasses.replace(
                 rec, name=planner_mod.shard_name(col, spec.index))
             if on_shard is not None:
@@ -1338,10 +1394,13 @@ class StreamingExecutor:
         (sequential mesh path: timed, blocking ``jax.device_put``); the
         measured copy feeds the fabric EWMA."""
         dst_id, dst_dev = self._d2d_target(mesh_plan, devices, dst_logical)
-        t0 = time.perf_counter()
-        arr = jax.device_put(rec.array, dst_dev)
-        jax.block_until_ready(arr)
-        copy_s = time.perf_counter() - t0
+        src_id = min(d.id for d in rec.array.devices())
+        with obs.span("zipflow.d2d", column=rec.name,
+                      bytes=int(rec.array.nbytes), src=src_id,
+                      dst=dst_id) as copy:
+            arr = jax.device_put(rec.array, dst_dev)
+            jax.block_until_ready(arr)
+        copy_s = copy.s
         self._observe_d2d_actual(int(arr.nbytes), copy_s)
         return dataclasses.replace(rec, array=arr), dst_id, dst_dev, copy_s
 
@@ -1369,6 +1428,18 @@ class StreamingExecutor:
                 if (self._programs.get(name) is None
                         or self._encoded.get(name) is not enc):
                     self.compile(name, enc)
+        return self._finish_sharded(
+            *self._run_mesh_legs(mesh_plan, on_ready, concurrent),
+            mesh_plan, on_ready)
+
+    def _run_mesh_legs(self, mesh_plan, on_ready=None,
+                       concurrent: bool | None = None,
+                       pad_to: int | None = None) -> tuple:
+        """Every device leg of a ``MeshExecutionPlan``, shards not yet
+        assembled: ``(results, shard_recs, per_device, device_launches,
+        d2d_copies)``, ``shard_recs`` mapping each sharded column to
+        ``(spec, ColumnExec, final device id, final device)`` per shard.
+        ``pad_to`` shapes one-span shards (``_run_group_chunked``)."""
         devices = jax.devices()
         missing = sorted({int(d) for d in mesh_plan.device_ids}
                          - set(range(len(devices))))
@@ -1380,7 +1451,8 @@ class StreamingExecutor:
         if concurrent is None:
             concurrent = active > 1
         if concurrent and active > 1:
-            return self._run_sharded_concurrent(mesh_plan, devices, on_ready)
+            return self._run_sharded_concurrent(mesh_plan, devices, on_ready,
+                                                pad_to)
         per_device: dict[int, tuple[str, ...]] = {}
         device_launches: dict[int, int] = {}
         results: dict[str, ColumnExec] = {}
@@ -1415,7 +1487,7 @@ class StreamingExecutor:
                 spec = next(s for s in mesh_plan.shards[col] if s.name == it)
                 rec = self._run_shard(col, spec,
                                       dplan.decisions[it].chunk_bytes,
-                                      dev, dplan.window)
+                                      dev, dplan.window, pad_to)
                 launches += rec.decode_launches
                 dev_recs.append(rec)
                 dst = redist_dst.get(it)
@@ -1431,12 +1503,10 @@ class StreamingExecutor:
             device_launches[dev_id] = launches
             if d_items:
                 self._observe_link_actuals(dev_id, dplan, dev_recs)
-        return self._finish_sharded(results, shard_recs, per_device,
-                                    device_launches, mesh_plan, on_ready,
-                                    d2d_copies=d2d_done)
+        return results, shard_recs, per_device, device_launches, d2d_done
 
-    def _run_sharded_concurrent(self, mesh_plan, devices,
-                                on_ready=None) -> "MeshRunResult":
+    def _run_sharded_concurrent(self, mesh_plan, devices, on_ready=None,
+                                pad_to: int | None = None) -> tuple:
         """Concurrent-issue mesh execution: stage every device's leg, spawn
         one transfer worker per link (shared host-staging budget from the
         plan's topology), and drive all device legs' decode generators from
@@ -1515,7 +1585,8 @@ class StreamingExecutor:
                 iss = engine.issuer(items, dev, {}, acq=acq, rel=rel,
                                     name=f"zipflow-xfer-d{dev_id}")
                 gen = self._device_leg(leg, shard_stage, iss, dplan.window,
-                                       on_ready=on_ready, on_shard=on_shard)
+                                       on_ready=on_ready, on_shard=on_shard,
+                                       pad_to=pad_to)
                 tasks[li] = (gen, iss)
                 legmeta[li] = (dev_id, dev, dplan)
             done = engine.drive(tasks)
@@ -1555,14 +1626,12 @@ class StreamingExecutor:
             self._observe_link_actuals(
                 dev_id, dplan,
                 list(whole_res.values()) + [r for _, _, r in recs])
-        return self._finish_sharded(results, shard_recs, per_device,
-                                    device_launches, mesh_plan, on_ready,
-                                    d2d_copies=d2d_done)
+        return results, shard_recs, per_device, device_launches, d2d_done
 
     def _finish_sharded(self, results: dict, shard_recs: dict,
                         per_device: dict, device_launches: dict,
-                        mesh_plan, on_ready=None,
-                        d2d_copies: dict | None = None) -> "MeshRunResult":
+                        d2d_copies: dict, mesh_plan,
+                        on_ready=None) -> "MeshRunResult":
         """Assemble shard outputs (shared by both mesh issue modes).  Shard
         tuples carry their FINAL device (redistributed shards arrive already
         copied), so the assembled ``NamedSharding`` reflects the plan's
@@ -1613,7 +1682,8 @@ class StreamingExecutor:
     # ------------------------------------------------------------- fused query
     def run_query(self, fq, encs: dict[str, plan_mod.Encoded] | None = None,
                   chunk_bytes: int | None | object = _DEFAULTS,
-                  window: int | None = None) -> "QueryExec":
+                  window: int | None = None,
+                  mesh: int | None = None) -> "QueryExec":
         """Execute a decode-fused query (``core.query.lower_query`` output).
 
         Non-fusible (resident) columns decode first through the normal planned
@@ -1626,12 +1696,21 @@ class StreamingExecutor:
         one in-flight staging slot, so the effective transfer window is
         ``max(1, window - 1)``.  Measured selectivity (the Reduce count lane)
         feeds the cost model's per-signature EWMA for future fused-vs-
-        materialize planning."""
+        materialize planning.
+
+        ``mesh=N`` with N > 1 row-shards the query over JAX's first N devices
+        (``_run_query_mesh``): chip i scans one contiguous row partition,
+        whose chunks its own host link carries, and the chips' partial
+        aggregates are added on the device before one D2H.  ``chunk_bytes``
+        is then a chip's compressed bytes per chunk.  ``mesh`` None or 1 is
+        the one-chip path above."""
         with obs.root("zipflow.query", query=fq.qplan.name):
+            if mesh is not None and int(mesh) > 1:
+                return self._run_query_mesh(fq, encs, chunk_bytes, window,
+                                            int(mesh))
             return self._run_query(fq, encs, chunk_bytes, window)
 
     def _run_query(self, fq, encs, chunk_bytes, window) -> "QueryExec":
-        from repro.core import fusion
         from repro.core.ir import query_chunk_layout
 
         if chunk_bytes is self._DEFAULTS:
@@ -1690,6 +1769,7 @@ class StreamingExecutor:
         t_issue = 0.0
         qname = fq.qplan.name
         scan = obs.current_scan()
+        dev_id = _device_id(None)
 
         def put_group(pieces: dict[str, np.ndarray]) -> dict[str, jnp.ndarray]:
             # ONE batched device_put per staging group: per-call dispatch
@@ -1698,7 +1778,7 @@ class StreamingExecutor:
             keys = list(pieces)
             nbytes = sum(int(p.nbytes) for p in pieces.values())
             with obs.span("zipflow.put", column=qname, bytes=nbytes,
-                          scan=scan) as put:
+                          device=dev_id, scan=scan) as put:
                 outs = jax.device_put([pieces[nm] for nm in keys])  # async
             t_issue += put.s
             return dict(zip(keys, outs))
@@ -1762,32 +1842,309 @@ class StreamingExecutor:
         with obs.span("zipflow.finalize", query=qname):
             # acc is tiny (lanes x segments): one D2H pull serves selectivity
             # and the finalized result without extra device slicing round-trips
-            acc_np = np.asarray(acc)
-            sel = float(fq.selectivity(acc_np))
-            for c in fq.fused_cols:
-                if c not in self.cost_model.profiles and encs and c in encs:
-                    from repro.core.compiler import build_graph
-                    self.cost_model.register(
-                        profile_from(c, encs[c], build_graph(encs[c])))
-                if c in self.cost_model.profiles:
-                    self.cost_model.observe_selectivity(c, sel)
-            traffic = self._query_traffic.get(graph.signature)
-            if traffic is None:
-                all_bufs = {**ops, **res_bufs}
-                traffic = (fusion.hbm_traffic_bytes(graph.stages, all_bufs),
-                           fusion.hbm_traffic_bytes(fq.prefuse_stages, all_bufs))
-                self._query_traffic[graph.signature] = traffic
-            compressed = sum(int(np.asarray(ops[b.name]).nbytes)
-                             for b in graph.buffers)
-            plain = (sum(int(encs[c].plain_nbytes) for c in fq.fused_cols)
-                     if encs else 0)
-            return QueryExec(
-                name=fq.qplan.name, result=fq.finalize(acc_np), acc=acc,
-                transfer_s=transfer_s, decode_s=decode_s,
-                n_chunks=K, decode_launches=K, selectivity=sel,
-                compressed_bytes=compressed, plain_bytes=plain,
-                traffic_bytes=traffic[0], prefuse_traffic_bytes=traffic[1],
-                resident=resident_execs)
+            return self._query_record(
+                fq, encs, acc, np.asarray(acc), res_bufs,
+                transfer_s=transfer_s, decode_s=decode_s, n_chunks=K,
+                decode_launches=K, resident=resident_execs)
+
+    def _mesh_partitions(self, fq, n_chips: int, layout):
+        """Row partition bounds of a row-sharded query (N + 1 ascending row
+        ids, partition i = chip i), and the resident columns'
+        ``MeshExecutionPlan`` whose shard i covers partition i (None without
+        resident columns).  Planned once per (resident columns, chips).
+
+        Resident columns are group-span sharded by ``plan_mesh_execution``
+        under ``placement="sharded"``, so shard i ends on chip i (decoded
+        there, or carried there by a D2D leg); their shard bounds are the
+        partitions.  Without them, partitions are equal runs of a multiple of
+        ``align * QUERY_CHUNK_LADDER[-1]`` rows, the last one short."""
+        n, N = int(fq.n_rows), int(n_chips)
+        key = (tuple(fq.resident), N, n, layout.align)
+        hit = self._mesh_plans.get(key)
+        if hit is not None:
+            return hit
+        if not fq.resident:
+            step = _ceil_to(-(-n // N),
+                            layout.align * planner_mod.QUERY_CHUNK_LADDER[-1])
+            bounds = tuple(min(i * step, n) for i in range(N)) + (n,)
+            self._mesh_plans[key] = (bounds, None)
+            return bounds, None
+        with obs.span("zipflow.plan", policy=self.policy):
+            profiles = {c: self.column_profile(c) for c in fq.resident}
+            rplan = planner_mod.plan_mesh_execution(
+                profiles, self.cost_model, n_devices=N, policy=self.policy,
+                chunk_bytes=self.chunk_bytes, chunk_decode=self.chunk_decode,
+                shard_threshold_bytes=0, placement="sharded")
+        bounds = None
+        for c in fq.resident:
+            specs = sorted(rplan.shards.get(c, ()), key=lambda sp: sp.index)
+            if len(specs) != N:
+                raise ValueError(
+                    f"resident column {c!r} does not split into {N} row "
+                    f"shards (group-span shardable, at least {2 * N} groups)")
+            b = tuple(sp.out_lo for sp in specs) + (specs[-1].out_hi,)
+            if bounds is not None and b != bounds:
+                raise ValueError(
+                    f"resident columns' row shards differ: {bounds} != {b}")
+            bounds = b
+        if bounds[0] != 0 or bounds[-1] != n or any(
+                b % layout.align for b in bounds[1:-1]):
+            raise ValueError(
+                f"resident row shards {bounds} do not cut {n} rows on the "
+                f"fused columns' {layout.align}-row alignment")
+        self._mesh_plans[key] = (bounds, rplan)
+        return bounds, rplan
+
+    def _mesh_schedule(self, fq, layout, bounds: tuple, chunk_bytes):
+        """Chunking shared by every chip of a row-sharded query, memoized
+        like the one-chip schedule: K chunks of ``c`` partition-local rows
+        (``c`` on the layout's alignment, so the longest partition pads to
+        K * c), and per chip and chunk each tile leaf's global word range
+        with its padded length."""
+        graph, ops = fq.graph, fq.operands
+        skey = (graph.signature, "mesh",
+                None if chunk_bytes is None else int(chunk_bytes), bounds)
+        sched = self._query_schedules.get(skey)
+        if sched is not None:
+            return sched
+        align = layout.align
+        sizes = [b - a for a, b in zip(bounds, bounds[1:])]
+        L = max(sizes)
+        ratios: dict[str, tuple[int, int]] = {}
+        per_elem = 0.0
+        for nm, spec in layout.tiled.items():
+            num = int(ops[spec.num_op][0]) if spec.num_op else int(spec.num)
+            ratios[nm] = (num, int(spec.den))
+            per_elem += num / spec.den * np.dtype(ops[nm].dtype).itemsize
+        chunk = (L if chunk_bytes is None else costmodel.aligned_chunk_elems(
+            chunk_bytes, per_elem, align))
+        K = -(-L // max(1, min(chunk, L)))
+        c = _ceil_to(-(-L // K), align)
+        n = bounds[-1]
+        slices = []             # chip -> chunk -> leaf -> (lo, hi, length)
+        for lo_row, hi_row in zip(bounds, bounds[1:]):
+            per_chunk = []
+            for k in range(K):
+                sl = {}
+                for nm, (num, den) in ratios.items():
+                    length = int(np.asarray(ops[nm]).shape[0])
+                    g0 = (lo_row * num) // den
+                    end = length if hi_row >= n else (hi_row * num) // den
+                    a, b = (k * c * num) // den, ((k + 1) * c * num) // den
+                    sl[nm] = (min(g0 + a, end), min(g0 + b, end), b - a)
+                per_chunk.append(sl)
+            slices.append(per_chunk)
+        sched = (tuple(layout.whole), tuple(ratios), K, c, tuple(sizes),
+                 slices)
+        self._query_schedules[skey] = sched
+        return sched
+
+    def _run_query_mesh(self, fq, encs, chunk_bytes, window,
+                        n_chips: int) -> "QueryExec":
+        """Row-sharded fused query over JAX's first ``n_chips`` devices.
+
+        1. Partitions: contiguous row ranges, one per chip, cut where the
+           resident columns' row shards are cut (``_mesh_partitions``).
+        2. Resident columns decode through the mesh path (``run_sharded``'s
+           legs): shard i, one span shaped like the padded partition, ends
+           on chip i; no chip decodes rows it does not own.
+        3. Chunks: every chip runs the same K chunks of its own partition
+           (``_mesh_schedule``); chip i's pieces go over link i, one
+           ``DispatchEngine`` transfer worker per chip, all in flight at
+           once.  Each chunk is ONE SPMD launch
+           (``MeshQueryChunkProgram``, compiled once for the mesh) adding
+           every chip's partial aggregate to that chip's accumulator.
+        4. Combine: the accumulators are summed across chips on the device
+           (``query.compile_combine``), then one D2H."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from repro.core.compiler import MESH_AXIS, mesh_of
+        from repro.core.ir import query_chunk_layout
+        from repro.core.query import mesh_lanes
+
+        if chunk_bytes is self._DEFAULTS:
+            chunk_bytes = self._fixed_chunk_bytes
+        devices = jax.devices()
+        if n_chips > len(devices):
+            raise ValueError(f"mesh of {n_chips} chips, but JAX has only "
+                             f"{len(devices)} device(s)")
+        chips = tuple(devices[:n_chips])
+        graph, ops, qname = fq.graph, fq.operands, fq.qplan.name
+        layout = query_chunk_layout(graph)
+        if layout is None:
+            raise ValueError(f"graph {graph.nesting!r} is not query-chunkable")
+        if fq.resident:
+            missing = [c for c in fq.resident if not encs or c not in encs]
+            if missing:
+                raise ValueError(
+                    f"resident columns need their Encoded blobs: {missing}")
+            for c in fq.resident:
+                if (self._programs.get(c) is None
+                        or self._encoded.get(c) is not encs[c]):
+                    self.compile(c, encs[c])
+        bounds, rplan = self._mesh_partitions(fq, n_chips, layout)
+        whole_names, tiled_names, K, c, sizes, slices = self._mesh_schedule(
+            fq, layout, bounds, chunk_bytes)
+        L = K * c
+        mesh = mesh_of(chips)
+        rows = NamedSharding(mesh, PartitionSpec(MESH_AXIS))
+        replicated = NamedSharding(mesh, PartitionSpec())
+        launches = {d.id: K for d in chips}
+
+        resident_execs: dict[str, ColumnExec] = {}
+        res_bufs: dict[str, jnp.ndarray] = {}
+        if rplan is not None:
+            _, shard_recs, _, dev_launches, _ = self._run_mesh_legs(
+                rplan, pad_to=L)
+            for d, k in dev_launches.items():
+                launches[d] = launches.get(d, 0) + k
+            for col in fq.resident:
+                recs = sorted(shard_recs[col], key=lambda t: t[0].index)
+                arrs = []
+                for i, (_, rec, dev_id, _) in enumerate(recs):
+                    if dev_id != chips[i].id:
+                        raise ValueError(f"shard {rec.name} ends on device "
+                                         f"{dev_id}, not chip {i}")
+                    arr = rec.array
+                    if arr.shape[0] != L:   # a shard decoded in many spans
+                        arr = jnp.pad(arr, (0, L - arr.shape[0]))
+                    arrs.append(arr)
+                glob = jax.make_array_from_single_device_arrays(
+                    (n_chips * L,), rows, arrs)
+                res_bufs[fq.resident_input(col)] = glob
+                enc = self._encoded[col]
+                resident_execs[col] = ColumnExec(
+                    name=col, array=glob,
+                    transfer_s=max(t[1].transfer_s for t in recs),
+                    decode_s=max(t[1].decode_s for t in recs),
+                    compressed_bytes=enc.compressed_nbytes,
+                    plain_bytes=enc.plain_nbytes,
+                    n_chunks=sum(t[1].n_chunks for t in recs),
+                    signature=self._graphs[col].signature,
+                    decode_launches=sum(t[1].decode_launches for t in recs),
+                    chunk_decoded=True,
+                    shard_devices=tuple(t[2] for t in recs))
+
+        # host staging: per chip, the whole leaves, then one item per chunk
+        # (its tile pieces, zero-padded past the partition's last word)
+        items: list[list] = []
+        whole_dest: list[list] = []
+        chunk_dest: list[list] = []
+        for i in range(n_chips):
+            with obs.span("zipflow.stage", column=qname, chip=i):
+                wd: list = [None]
+                cd: list = [None] * K
+                its = [(qname, wd, 0, [np.asarray(ops[nm])
+                                        for nm in whole_names])]
+                for k in range(K):
+                    pieces = []
+                    for nm in tiled_names:
+                        lo, hi, length = slices[i][k][nm]
+                        src = np.asarray(ops[nm])
+                        if hi - lo == length:
+                            pieces.append(src[lo:hi])
+                        else:
+                            p = np.zeros(length, src.dtype)
+                            p[:hi - lo] = src[lo:hi]
+                            pieces.append(p)
+                    its.append((qname, cd, k, pieces))
+            items.append(its)
+            whole_dest.append(wd)
+            chunk_dest.append(cd)
+
+        lanes = mesh_lanes(graph.n_out)
+        prog = self.cache.get_mesh_query_chunk(graph, c, chips, lanes)
+        win = 2 if window is None else max(1, int(window))
+        eff = max(1, win - 1)
+        residual = dispatch = 0.0
+        issue_s = [dict() for _ in chips]
+        engine = DispatchEngine()
+        try:
+            issuers = [engine.issuer(items[i], chips[i], issue_s[i],
+                                     name=f"zipflow-xfer-d{chips[i].id}")
+                       for i in range(n_chips)]
+            acc = jax.device_put(np.zeros(n_chips * lanes, np.float32), rows)
+            n_valid = jax.device_put(np.asarray(sizes, np.int32), rows)
+            whole = None
+            for k in range(K):
+                for iss in issuers:       # item 0 holds the whole leaves
+                    iss.advance(1 + min(k + eff, K))
+                with obs.span("zipflow.wait_h2d", column=qname,
+                              chunk=k) as wait:
+                    for iss in issuers:
+                        iss.wait(k + 2)
+                    if whole is None:
+                        whole = {nm: jax.make_array_from_single_device_arrays(
+                            np.shape(ops[nm]), replicated,
+                            [wd[0][j] for wd in whole_dest])
+                            for j, nm in enumerate(whole_names)}
+                        jax.block_until_ready(list(whole.values()))
+                    pieces = [cd[k] for cd in chunk_dest]
+                    jax.block_until_ready(pieces)
+                residual += wait.s
+                tiled = {nm: jax.make_array_from_single_device_arrays(
+                    (n_chips * pieces[0][j].shape[0],), rows,
+                    [p[j] for p in pieces])
+                    for j, nm in enumerate(tiled_names)}
+                with obs.span("zipflow.launch", program="query_chunk_mesh",
+                              chunk=k) as launch:
+                    acc = prog({**tiled, **res_bufs}, whole,
+                               np.int32(k * c), n_valid, acc)
+                dispatch += launch.s
+                for cd in chunk_dest:
+                    cd[k] = None          # chunk k's pieces may go
+            with obs.span("zipflow.wait_decode", column=qname) as done:
+                jax.block_until_ready(acc)
+            dispatch += done.s
+        finally:
+            engine.close()
+        transfer_s = max(sum(d.values()) for d in issue_s) + residual
+
+        used = sum(1 for z in sizes if z > 0)
+        obs.inc("mesh_rows", sum(sizes))
+        obs.inc("mesh_rows_full", max(sizes) * used)
+        obs.inc("mesh_chips", used)
+        combine = self.cache.get_combine(chips, lanes)
+        with obs.span("zipflow.combine", query=qname, chips=n_chips):
+            total = combine(acc)
+            acc_np = np.asarray(total)[:graph.n_out]
+        with obs.span("zipflow.finalize", query=qname):
+            return self._query_record(
+                fq, encs, total, acc_np, res_bufs, transfer_s=transfer_s,
+                decode_s=dispatch, n_chunks=K, decode_launches=K,
+                resident=resident_execs, device_launches=launches)
+
+    def _query_record(self, fq, encs, acc, acc_np: np.ndarray,
+                      res_bufs: dict, **fields) -> "QueryExec":
+        """A finished query's ``QueryExec`` from its accumulator on the host
+        (``acc_np``): selectivity into the cost model, the fused graph's
+        traffic, the byte counts."""
+        from repro.core import fusion
+
+        graph, ops = fq.graph, fq.operands
+        sel = float(fq.selectivity(acc_np))
+        for c in fq.fused_cols:
+            if c not in self.cost_model.profiles and encs and c in encs:
+                from repro.core.compiler import build_graph
+                self.cost_model.register(
+                    profile_from(c, encs[c], build_graph(encs[c])))
+            if c in self.cost_model.profiles:
+                self.cost_model.observe_selectivity(c, sel)
+        traffic = self._query_traffic.get(graph.signature)
+        if traffic is None:
+            all_bufs = {**ops, **res_bufs}
+            traffic = (fusion.hbm_traffic_bytes(graph.stages, all_bufs),
+                       fusion.hbm_traffic_bytes(fq.prefuse_stages, all_bufs))
+            self._query_traffic[graph.signature] = traffic
+        compressed = sum(int(np.asarray(ops[b.name]).nbytes)
+                         for b in graph.buffers)
+        plain = (sum(int(encs[c].plain_nbytes) for c in fq.fused_cols)
+                 if encs else 0)
+        return QueryExec(
+            name=fq.qplan.name, result=fq.finalize(acc_np), acc=acc,
+            selectivity=sel, compressed_bytes=compressed, plain_bytes=plain,
+            traffic_bytes=traffic[0], prefuse_traffic_bytes=traffic[1],
+            **fields)
 
     def unregister(self, name: str) -> None:
         """Drop one registered blob's per-column state (profile, schedules,

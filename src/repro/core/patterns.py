@@ -85,10 +85,14 @@ class Ctx:
     out_idx: global output indices of the elements being produced (int32 vector).
     starts:  global start offset of each input block (0 for the jnp backend, the block
              origin inside Pallas kernels).
+    n_valid: where set, elements at ``out_idx >= n_valid`` are padding (a chip's
+             row partition padded to the mesh's common shape) that a ``Reduce``
+             must not count; fused closures pass it on unchanged.
     """
 
     out_idx: jnp.ndarray
     starts: tuple[Any, ...] = ()
+    n_valid: Any = None
 
 
 class Stage:
@@ -319,11 +323,11 @@ def compose_fp(first: FullyParallel, second: FullyParallel) -> FullyParallel:
     n_first = len(first.inputs)
 
     def fused(ctx: Ctx, *blocks):
-        f_ctx = Ctx(out_idx=ctx.out_idx, starts=ctx.starts[:n_first])
+        f_ctx = dataclasses.replace(ctx, starts=ctx.starts[:n_first])
         mid = f_fn(f_ctx, *blocks[:n_first]).astype(first.out_dtype)
         # None start: `mid` is an in-register intermediate positionally aligned with
         # out_idx -- the consumer must not gather it by global index
-        s_ctx = Ctx(out_idx=ctx.out_idx, starts=(None,) + ctx.starts[n_first:])
+        s_ctx = dataclasses.replace(ctx, starts=(None,) + ctx.starts[n_first:])
         return s_fn(s_ctx, mid, *blocks[n_first:])
 
     return FullyParallel(
@@ -345,10 +349,10 @@ def compose_positional(first: FullyParallel, cons: Stage, j: int) -> Stage:
     f_fn, c_fn = first.fn, cons.fn
 
     def fused(ctx: Ctx, *blocks):
-        f_ctx = Ctx(out_idx=ctx.out_idx, starts=ctx.starts[j:j + n_first])
+        f_ctx = dataclasses.replace(ctx, starts=ctx.starts[j:j + n_first])
         mid = f_fn(f_ctx, *blocks[j:j + n_first]).astype(first.out_dtype)
         s_starts = ctx.starts[:j] + (None,) + ctx.starts[j + n_first:]
-        return c_fn(Ctx(out_idx=ctx.out_idx, starts=s_starts),
+        return c_fn(dataclasses.replace(ctx, starts=s_starts),
                     *blocks[:j], mid, *blocks[j + n_first:])
 
     new = dataclasses.replace(

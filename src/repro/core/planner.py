@@ -322,6 +322,47 @@ def plan_execution(profiles: Mapping[str, ColumnProfile] | Sequence[ColumnProfil
         modeled_makespan_s=makespan_s, baselines=baselines)
 
 
+# ------------------------------------------------------------ fused queries
+
+# chunk counts a fused query's row-chunk search tries
+QUERY_CHUNK_LADDER = (1, 2, 4, 8)
+
+
+def query_chunk_bytes(name: str, compressed_bytes: int, transfer_s: float,
+                      decode_s: float, overhead_s: float, window: int,
+                      link_scales: Sequence[float] = (1.0,)) -> int | None:
+    """Compressed bytes per fused-query chunk on each chip, or None for one
+    chunk.  The fused columns form ONE shared-schedule job; the chunk count
+    is the ``QUERY_CHUNK_LADDER`` entry with the least modeled makespan,
+    each extra launch priced at ``overhead_s``.  A row-sharded query streams
+    1/N of the job over each of N host links, link d at ``link_scales[d]``
+    times the calibrated transfer time, so a count is priced by its slowest
+    chip; one link at scale 1.0 is the one-chip query."""
+    n = len(link_scales)
+    best_k, best_t = 1, None
+    for k in QUERY_CHUNK_LADDER:
+        mk = 0.0
+        for scale in sorted(set(link_scales)):
+            t, d = transfer_s * scale / n, decode_s / n
+            if costmodel_mod.serial_host():
+                # one resource: no transfer/decode overlap, chunking only
+                # buys launch overhead
+                m = t + d + (k - 1) * overhead_s
+            else:
+                m = scheduler.simulate_stream(
+                    [scheduler.Job(name, t, d)],
+                    [ChunkInfo(n_chunks=k, chunk_decode=k > 1,
+                               launch_overhead_s=overhead_s)],
+                    window=window)
+            mk = max(mk, m)
+        if best_t is None or mk < best_t - 1e-12:
+            best_k, best_t = k, mk
+    if best_k == 1:
+        return None
+    per_chip = -(-int(compressed_bytes) // n)
+    return -(-per_chip // best_k)
+
+
 # ------------------------------------------------------------ mesh planning
 
 SHARD_SEP = "::shard"

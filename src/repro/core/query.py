@@ -27,6 +27,10 @@ Columns whose decode is not Fully-Parallel (ANS, RLE inside a multi-column
 query) fall back to **resident** inputs: decoded once by the normal executor
 path and gathered at the global row index by every fused chunk launch
 (``BufSpec("row")``).
+
+Row-sharded over a mesh of chips, each chip sums its own rows' partials
+into ``mesh_lanes`` accumulator lanes; ``compile_combine`` adds the chips'
+accumulators on the device, so one D2H reads the answer.
 """
 from __future__ import annotations
 
@@ -34,6 +38,7 @@ import dataclasses
 import hashlib
 from typing import Any, Mapping, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -218,6 +223,35 @@ def blocked_segment_sum(lanes: Sequence[jnp.ndarray],
                       for v in lanes], axis=1)
 
 
+# A row-sharded query keeps one accumulator per chip, padded to a whole
+# number of this many lanes, so every query's combine is one program.
+MESH_LANES = 128
+
+
+def mesh_lanes(n_out: int) -> int:
+    """Accumulator lanes a chip holds for ``n_out`` partial aggregates."""
+    return -(-int(n_out) // MESH_LANES) * MESH_LANES
+
+
+def compile_combine(devices, lanes: int):
+    """The combine of a row-sharded query: each chip's ``lanes`` partial
+    aggregates (a global array sharded along the mesh axis) summed across
+    the chips by one ``psum`` on the device; the (lanes,) result is
+    replicated, so one D2H from any chip reads it."""
+    from jax.sharding import PartitionSpec
+
+    from repro.core.compiler import MESH_AXIS, _named, mesh_of
+
+    def combine(acc):
+        return jax.lax.psum(acc, MESH_AXIS)
+
+    fn = jax.shard_map(_named(combine, "combine", "mesh"),
+                       mesh=mesh_of(devices),
+                       in_specs=PartitionSpec(MESH_AXIS),
+                       out_specs=PartitionSpec())
+    return jax.jit(fn)
+
+
 # ------------------------------------------------------------------ lowering
 
 def _all_fp(stages: list[Stage]) -> bool:
@@ -379,6 +413,9 @@ def lower_query(qplan: QueryPlan, encs: Mapping[str, Any]) -> FusedQuery:
                 env[cn] = v
         for p in ipreds:
             m = p.mask(env[p.col])
+            mask = m if mask is None else mask & m
+        if ctx.n_valid is not None:         # a chip's padded partition
+            m = ctx.out_idx < ctx.n_valid
             mask = m if mask is None else mask & m
         w = (jnp.ones(ctx.out_idx.shape, jnp.float32) if mask is None
              else mask.astype(jnp.float32))

@@ -120,7 +120,8 @@ class ColumnPipeline:
                  async_dispatch: bool = False, placement: str | None = None):
         self.plans = plans
         # mesh=N enables topology-aware multi-device planning: run_sharded()
-        # partitions columns (and group-span shards) over N devices;
+        # partitions columns (and group-span shards) over N devices, and
+        # run_query() row-shards a fused query over N chips;
         # placement="sharded" pins each shard's FINAL device so the planner
         # may land bytes elsewhere and rebalance over the D2D fabric tier
         self.mesh = mesh
@@ -270,13 +271,20 @@ class ColumnPipeline:
         partial aggregates reach HBM.  The in-flight window AND the row-chunk
         count come from the cost model (memoized per query digest): the fused
         columns form ONE shared-schedule job, and the chunk count is chosen by
-        ``simulate_stream`` over a small ladder, pricing each extra launch at
-        the calibrated overhead — on hosts where launch overhead dominates
-        (CPU) this collapses to a single fused launch; where transfer/decode
-        overlap pays, it chunks.  An explicitly configured fixed ``chunk_bytes``
-        overrides the search, like ``run``.  The fused accumulator costs one
-        staging slot, accounted inside ``StreamingExecutor.run_query``."""
-        from repro.core import scheduler
+        ``planner.query_chunk_bytes`` over a small ladder, pricing each extra
+        launch at the calibrated overhead — on hosts where launch overhead
+        dominates (CPU) this collapses to a single fused launch; where
+        transfer/decode overlap pays, it chunks.  An explicitly configured
+        fixed ``chunk_bytes`` overrides the search, like ``run``.  The fused
+        accumulator costs one staging slot, accounted inside
+        ``StreamingExecutor.run_query``.
+
+        With ``mesh=N`` (N > 1) the query is row-sharded over N chips
+        (``StreamingExecutor.run_query``): the search prices each chip's
+        share of the job on its own host link, so the chunk size is a
+        chip's, and the resident columns are row shards placed by chip
+        whatever ``placement`` says."""
+        from repro.core import planner
 
         fq, encs = self.lower_query(qplan)
         key = qplan.digest()
@@ -287,38 +295,28 @@ class ColumnPipeline:
                 if isinstance(self.chunk_bytes, int):
                     cb = self.chunk_bytes       # fixed size: user override
                 else:
-                    from repro.core.costmodel import serial_host
-
                     cm = self.executor.cost_model
                     t_tr = d_fused = oh = 0.0
                     for c in fq.fused_cols:
                         t_tr += cm.predict(c)[0]
                         d_fused += cm.fused_decode_s(c)
                         oh = max(oh, cm.launch_overhead_s(c))
-                    best_k, best_t = 1, None
-                    for k in (1, 2, 4, 8):
-                        if serial_host():
-                            # one resource: no transfer/decode overlap,
-                            # chunking only buys launch overhead
-                            mk = t_tr + d_fused + (k - 1) * oh
-                        else:
-                            mk = scheduler.simulate_stream(
-                                [scheduler.Job(qplan.name, t_tr, d_fused)],
-                                [scheduler.ChunkInfo(n_chunks=k,
-                                                     chunk_decode=k > 1,
-                                                     launch_overhead_s=oh)],
-                                window=ep.window)
-                        if best_t is None or mk < best_t - 1e-12:
-                            best_k, best_t = k, mk
+                    scales = (1.0,)
+                    if self.mesh is not None and self.mesh > 1:
+                        scales = tuple(cm.topology.scale(d)
+                                       for d in range(self.mesh))
                     comp = sum(self._encoded[c].compressed_nbytes
                                for c in fq.fused_cols)
-                    cb = None if best_k == 1 else -(-comp // best_k)
+                    cb = planner.query_chunk_bytes(
+                        qplan.name, comp, t_tr, d_fused, oh, ep.window,
+                        link_scales=scales)
                 cfg = (ep.window, cb)
                 self._query_cfg[key] = cfg
         win, cb = cfg
         if window is not None:
             win = window
-        return self.executor.run_query(fq, encs, chunk_bytes=cb, window=win)
+        return self.executor.run_query(fq, encs, chunk_bytes=cb, window=win,
+                                       mesh=self.mesh)
 
     def modeled_makespan(self, pipeline: bool = True, johnson: bool = True,
                          chunked: bool = False) -> float:

@@ -21,9 +21,11 @@ session is active, and only then:
 
 * the seconds of every span, summed under its name; a root opened inside
   another adds nothing, as its time is in the outer root's;
-* counters, ``inc(name, n)``.  There is one, ``plan_changes``: plans whose
-  issue order or per-column decisions differ from the previous plan over the
-  same column set.
+* counters, ``inc(name, n)``: ``plan_changes``, plans whose issue order or
+  per-column decisions differ from the previous plan over the same column
+  set; and, per row-sharded query, ``mesh_rows`` (rows scanned, summed over
+  chips), ``mesh_rows_full`` (the fullest chip's rows times the chips that
+  got rows) and ``mesh_chips`` (the chips that got rows).
 
 The registry is never reset: over a process with one profiler session, as a
 traced benchmark run, it holds that session's totals.
@@ -36,7 +38,7 @@ import time
 
 from jax.profiler import TraceAnnotation
 
-COUNTERS = ("plan_changes",)
+COUNTERS = ("plan_changes", "mesh_rows", "mesh_rows_full", "mesh_chips")
 
 _registry: dict[str, float] = dict.fromkeys(COUNTERS, 0)
 _lock = threading.Lock()
